@@ -332,6 +332,9 @@ def main() -> None:
                     help="directory holding the committed snapshots "
                          "(default: repo root)")
     args = ap.parse_args()
+    from repro.core import jaxcompat
+    jaxcompat.compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     from benchmarks import (algo_writes, fig8_trace, fig_curves,
                             kernels_bench, paper_tables, planner_bench,
                             roofline, streams_bench)

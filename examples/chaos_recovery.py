@@ -4,11 +4,13 @@ survive a tier outage and print the regret table.
 Phase 1 (crash recovery): a child process ingests the fleet with
 chunk-boundary checkpointing on and SIGKILLs *itself* at a seeded chunk
 that is not a checkpoint boundary (the worst case: the cursor is past
-the last committed save, an async write may be mid-flight). The parent
-then restores the latest committed checkpoint onto a freshly built
-engine, replays the remaining chunks, and asserts the final reservoirs
-and every host ledger are bitwise identical to an uninterrupted
-reference run (sha256 digests printed for both).
+the last committed save, an async write may be mid-flight). The child
+runs before the parent touches a device (an accelerator serves one
+process at a time). The parent then runs an uninterrupted reference,
+restores the latest committed checkpoint onto a freshly built engine,
+replays the remaining chunks, and asserts the final reservoirs and
+every host ledger are bitwise identical to the reference (sha256
+digests printed for both).
 
 Phase 2 (tier outage): the recovered engine keeps serving; mid-window
 the DRAM tier is declared failed — affected tenants are evacuated
@@ -133,14 +135,9 @@ def main():
         child(args)
         return
 
-    # ---- reference: the uninterrupted run ------------------------------
-    ref = build_engine(args.tenants, args.total_docs, args.k)
-    for i in range(args.chunks):
-        ref.ingest_dense(make_chunk(ref, i, args.seed))
-    ref_digest = digest(ref)
-    print(f"reference: {args.chunks} chunks, digest {ref_digest[:16]}…")
-
-    # ---- phase 1: kill -9 mid-window, restore, replay ------------------
+    # ---- phase 1: kill -9 mid-window ------------------------------------
+    # the child runs before this process touches a device: an accelerator
+    # belongs to one process at a time, and the parent would hold it
     proc = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--role", "child",
          "--tenants", str(args.tenants), "--k", str(args.k),
@@ -158,6 +155,15 @@ def main():
     print(f"child killed -9 at chunk {args.kill_at} "
           f"(rc={proc.returncode})")
 
+    # ---- reference: the uninterrupted run ------------------------------
+    ref = build_engine(args.tenants, args.total_docs, args.k)
+    for i in range(args.chunks):
+        ref.ingest_dense(make_chunk(ref, i, args.seed))
+    ref_digest = digest(ref)
+    print(f"reference: {args.chunks} chunks, digest {ref_digest[:16]}…")
+    del ref
+
+    # ---- restore the child's latest checkpoint and replay ----------------
     eng = build_engine(args.tenants, args.total_docs, args.k,
                        events_path=os.path.join(args.out, "events.jsonl"))
     ck = FleetCheckpointer(args.ckpt_dir, every=args.ckpt_every)

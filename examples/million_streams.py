@@ -2,7 +2,8 @@
 top-K retention window for 1M tenant streams with the fleet axis
 shard_map-ped across devices.
 
-Phases (all on a forced multi-device CPU mesh — no hardware needed):
+Phases (on every local device — a TPU host's chips, or a CPU split
+into ``--devices`` virtual devices, so no hardware is needed):
 
 1. **Plan** — one sharded ``core.shp_jax`` candidate-grid solve over all
    M streams' 3-tier cost arrays, then cross-shard water-filling
@@ -26,41 +27,25 @@ Run:
   PYTHONPATH=src python examples/million_streams.py [--streams 1000000]
   PYTHONPATH=src python examples/million_streams.py --ci   # 64k, CI scale
 
-``--devices N`` forces an N-device CPU mesh via
-``--xla_force_host_platform_device_count`` (set before jax imports);
+``--devices N`` shards over N devices (default: all local devices). On
+the CPU it also splits the host into N virtual devices
+(``jax_num_cpu_devices``, which only the CPU backend reads);
 ``--devices 1`` runs the same window unsharded for comparison.
 """
 import argparse
 import json
 import os
-import sys
 import time
 
+import numpy as np
 
-def _pre_parse_devices(argv):
-    """--devices must take effect before jax is imported."""
-    ap = argparse.ArgumentParser(add_help=False)
-    ap.add_argument("--devices", type=int, default=8)
-    args, _ = ap.parse_known_args(argv)
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "--xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={args.devices}"
-        ).strip()
-    return args.devices
+import jax
 
-
-_DEVICES = _pre_parse_devices(sys.argv[1:])
-
-import numpy as np  # noqa: E402
-
-import jax  # noqa: E402
-
-from repro.core import constraints as cons  # noqa: E402
-from repro.core import shp_jax  # noqa: E402
-from repro.obs import Observability, ObsConfig  # noqa: E402
-from repro.parallel import fleet  # noqa: E402
-from repro.streams import StreamEngine, StreamSpec, logmem, planner  # noqa: E402
+from repro.core import constraints as cons
+from repro.core import shp_jax
+from repro.obs import Observability, ObsConfig
+from repro.parallel import fleet
+from repro.streams import StreamEngine, StreamSpec, logmem, planner
 
 
 def fleet_cost_arrays(rng, m, n_docs, k):
@@ -146,7 +131,8 @@ def dense_chunks(rng, m, w, n_chunks, lm=0, lw=0):
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--devices", type=int, default=8)
+    ap.add_argument("--devices", type=int, default=None,
+                    help="fleet shards (default: all local devices)")
     ap.add_argument("--streams", type=int, default=1_000_000)
     ap.add_argument("--docs", type=int, default=256,
                     help="docs per stream in the window")
@@ -172,13 +158,16 @@ def main():
                          "tenants")
     ap.add_argument("--out", default="bench_out/million_streams.json")
     args = ap.parse_args()
+    if args.devices is not None:
+        # the CPU backend only: a TPU host's device count is its chips
+        jax.config.update("jax_num_cpu_devices", args.devices)
     if args.ci:
         args.streams = min(args.streams, 64_000)
     lm = (args.logmem_streams if args.logmem_streams is not None
           else (64 if args.ci else 0))
     lk, lw = args.logmem_k, args.logmem_chunk
 
-    mesh = fleet.fleet_mesh(args.devices) if args.devices > 1 else None
+    mesh = fleet.fleet_mesh(args.devices)
     shards = fleet.n_shards(mesh)
     m, k = args.streams, args.topk
     if lm and lm % max(shards, 1):

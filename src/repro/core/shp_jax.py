@@ -32,8 +32,8 @@ data become *static jit keys* computed on the host before tracing):
   order: no-migration subsets ascending by size, then cascades).
 
 Float64 / x64 policy (documented in the README): the solver computes
-in float64 via the scoped ``jax.experimental.enable_x64`` context
-(CPU/GPU default), matching the NumPy oracle to a few ulps — the
+in float64 via the scoped ``jaxcompat.enable_x64`` context
+(CPU default), matching the NumPy oracle to a few ulps — the
 residual divergence is transcendental (``log``) codegen and XLA fma
 contraction, bounded by ~1e-12 relative on totals; the property tests
 pin this. On TPU (or with ``precision="float32"``) the solver runs
@@ -47,16 +47,11 @@ import itertools
 
 import numpy as np
 
-try:  # keep `core.shp` importable without jax (the NumPy oracle stands)
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-
-    _HAVE_JAX = True
-except Exception:  # pragma: no cover - exercised only without jax
-    _HAVE_JAX = False
+import jax
+import jax.numpy as jnp
 
 from . import constraints as constraints_mod
+from . import jaxcompat
 
 MAX_DEVICE_TIERS = 4  # the exact joint enumeration (shp._ENUM_MAX_STEPS + 1)
 _MIN_PAD = 8  # M is padded to a power of two >= this (bounds jit cache)
@@ -86,9 +81,9 @@ def _executor():
 
 
 class DeviceSolverUnavailable(RuntimeError):
-    """Raised when the device solver cannot take this problem (no jax,
-    or a hierarchy deeper than the exact enumeration supports) — the
-    caller falls back to the NumPy oracle."""
+    """Raised when the device solver cannot take this problem (a
+    hierarchy deeper than the exact enumeration supports) — the caller
+    falls back to the NumPy oracle."""
 
 
 @functools.lru_cache(maxsize=None)
@@ -601,7 +596,7 @@ def _pallas_group(solve_ops, subs, ts, interior, pool, w_pool, key_idx,
     fold(val, [bounds[:, j] for j in range(t - 1)], interior)
 
 
-@functools.partial(jax.jit if _HAVE_JAX else lambda f, **kw: f,
+@functools.partial(jax.jit,
                    static_argnames=("t", "constrained", "capfin",
                                     "slo_any", "use_pallas"))
 def _plan_jit(cw, cr, cs, n, k, rpw, cap, lat, slo, *, t, constrained,
@@ -637,8 +632,6 @@ def plan_ntier_arrays_jax(cw, cr, cs, n, k, rpw, *, cap=None, lat=None,
     enumeration does not cover (T > 4) — callers fall back to the
     NumPy oracle.
     """
-    if not _HAVE_JAX:
-        raise DeviceSolverUnavailable("jax is not importable")
     cw = np.asarray(cw, np.float64)
     m, t = cw.shape
     if not 2 <= t <= MAX_DEVICE_TIERS:
@@ -657,12 +650,11 @@ def plan_ntier_arrays_jax(cw, cr, cs, n, k, rpw, *, cap=None, lat=None,
     # the host's np.any data gates, lifted to static jit keys
     capfin = tuple(bool(np.any(np.isfinite(cap_h[:, j]))) for j in range(t))
     slo_any = bool(np.any(np.isfinite(slo_h)))
+    on_tpu = jaxcompat.platform() == "tpu"
     if use_pallas is None:
-        from repro.kernels.plan_solve import ops as solve_ops
-        use_pallas = solve_ops.on_tpu()
+        use_pallas = on_tpu
     if precision is None:
-        from repro.kernels.plan_solve import ops as solve_ops
-        precision = ("float32" if solve_ops.on_tpu()
+        precision = ("float32" if on_tpu
                      else (DEFAULT_PRECISION_CONSTRAINED if constrained
                            else DEFAULT_PRECISION_UNCONSTRAINED))
     np_dtype = np.float64 if precision == "float64" else np.float32
@@ -701,7 +693,7 @@ def plan_ntier_arrays_jax(cw, cr, cs, n, k, rpw, *, cap=None, lat=None,
     _key = (t, constrained, capfin, slo_any, use_pallas, chunk, precision)
 
     def _solve(lo_i):
-        with enable_x64(precision == "float64"):
+        with jaxcompat.enable_x64(precision == "float64"):
             out = _probe.track(_plan_jit, *_chunk_args(lo_i), key=_key,
                                t=t, constrained=constrained, capfin=capfin,
                                slo_any=slo_any, use_pallas=use_pallas)
@@ -738,9 +730,9 @@ def _plan_sharded_fn(mesh, t, constrained, capfin, slo_any, use_pallas):
                            capfin=capfin, slo_any=slo_any,
                            use_pallas=use_pallas)
     spec = fleet_mod.row_spec()
-    return jax.jit(fleet_mod.shard_map(
+    return jax.jit(jaxcompat.shard_map(
         fn, mesh=mesh, in_specs=(spec,) * 9,
-        out_specs=(spec, spec, spec), check_rep=False))
+        out_specs=(spec, spec, spec)))
 
 
 def _plan_sharded(args, m, t, mesh, constrained, capfin, slo_any,
@@ -767,7 +759,7 @@ def _plan_sharded(args, m, t, mesh, constrained, capfin, slo_any,
     key = (obs_jits.mesh_key(mesh), t, constrained, capfin, slo_any,
            use_pallas, per, precision)
     sh = fleet_mod.row_sharding(mesh)
-    with enable_x64(precision == "float64"):
+    with jaxcompat.enable_x64(precision == "float64"):
         dev = [jax.device_put(_padr(a), sh) for a in args]
         out = probe.track(fn, *dev, key=key)
         val, bounds, mig = (np.asarray(o) for o in out)

@@ -19,8 +19,6 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-NEG_INF = jnp.float32(-jnp.inf)
-
 
 class ReservoirState(NamedTuple):
     scores: jax.Array  # (K,) float32, sorted descending, -inf padded

@@ -1,6 +1,8 @@
 # Compute hot-spots of the paper's pipeline, as Pallas TPU kernels
-# (pl.pallas_call + BlockSpec VMEM tiling), validated in interpret mode on
-# CPU against the ref.py oracles:
+# (pl.pallas_call + BlockSpec VMEM tiling). Each has a ref.py oracle; on
+# TPU the kernels compile (tests/test_tpu_compile.py compiles the fleet
+# path's kernels for a described v5e chip), on CPU they run in interpret
+# mode (repro.core.jaxcompat.pallas_interpret):
 #   entropy_scores — fused interestingness scoring (entropy+NLL over vocab tiles)
 #   topk_filter    — streaming reservoir threshold scan (Fig. 2/3 inner loop)
 #   batched_topk   — 2-D (stream, tile) threshold scan for the multi-tenant

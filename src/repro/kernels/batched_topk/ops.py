@@ -1,5 +1,5 @@
-"""Public wrapper for the batched threshold filter: pad the trailing axis,
-run the 2-D kernel (interpret off-TPU), strip the padding.
+"""Public wrapper for the batched threshold filter: pad both axes, run
+the 2-D kernel (interpret mode on CPU only), strip the padding.
 
 The composed survivor-extraction + exact per-stream merge lives in
 ``repro.streams.engine.filtered_update`` (streams layer sits above kernels).
@@ -11,17 +11,13 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.core import jaxcompat
+
+from ..common import pad_rows, row_tiling
 from . import ref
 from .batched_topk import batched_topk_pallas
 
 NEG_BIG = -1e30
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 @partial(jax.jit, static_argnames=("block_n", "use_pallas"))
@@ -42,8 +38,11 @@ def batched_topk_filter(scores, thresholds, *, block_n: int = 512,
                  constant_values=NEG_BIG)
     thr = thresholds.astype(jnp.float32)
     if use_pallas:
+        bm, mp = row_tiling(m)
         mask, counts, tmax = batched_topk_pallas(
-            sp, thr, block_n=bn, interpret=not _on_tpu())
+            pad_rows(sp, mp, NEG_BIG), pad_rows(thr, mp, 0.0), block_n=bn,
+            block_m=bm, interpret=jaxcompat.pallas_interpret())
+        mask, counts, tmax = mask[:m], counts[:m], tmax[:m]
     else:
         mask, counts, tmax = ref.batched_topk_filter(sp, thr, bn)
     return mask[:, :n], counts, tmax

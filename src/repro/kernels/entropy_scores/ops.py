@@ -1,5 +1,5 @@
 """Jit'd public wrapper for the entropy+NLL kernel: pads to tile multiples,
-runs the Pallas kernel (interpret=True off-TPU), slices back."""
+runs the Pallas kernel (interpret mode on CPU only), slices back."""
 from __future__ import annotations
 
 from functools import partial
@@ -7,15 +7,10 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.core import jaxcompat
+
 from . import ref
 from .entropy_scores import NEG_BIG, entropy_nll_pallas
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 @partial(jax.jit, static_argnames=("block_b", "block_v", "use_pallas"))
@@ -32,5 +27,5 @@ def entropy_nll(logits, labels, *, block_b: int = 8, block_v: int = 2048,
     lp = jnp.pad(logits, ((0, pad_b), (0, pad_v)), constant_values=NEG_BIG)
     lab = jnp.pad(labels.astype(jnp.int32), ((0, pad_b),))
     ent, nll = entropy_nll_pallas(lp, lab, block_b=bb, block_v=bv,
-                                  interpret=not _on_tpu())
+                                  interpret=jaxcompat.pallas_interpret())
     return ent[:b], nll[:b]

@@ -1,5 +1,5 @@
 """Jit'd public wrapper: pads sequences to tile multiples (padded keys are
-masked via the in-kernel position check), interpret mode off-TPU."""
+masked via the in-kernel position check), interpret mode on CPU only."""
 from __future__ import annotations
 
 from functools import partial
@@ -7,15 +7,10 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.core import jaxcompat
+
 from . import ref
 from .flash_attention import flash_attention_pallas
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k",
@@ -39,5 +34,5 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     # are garbage and sliced off here.
     out = flash_attention_pallas(qp, kp, vp, causal=causal, window=window,
                                  block_q=bq, block_k=bk,
-                                 interpret=not _on_tpu())
+                                 interpret=jaxcompat.pallas_interpret())
     return out[:, :sq]

@@ -1,5 +1,5 @@
 """Public wrapper for the fused logmem admission scan: pad the trailing
-axis, run the 2-D kernel (interpret off-TPU), strip the padding.
+axes, run the 2-D kernel (interpret mode on CPU only), strip the padding.
 
 The composed threshold-update epilogue (chunk order statistic, decayed
 fold, phase commit) lives in ``repro.streams.logmem.update`` — the
@@ -12,18 +12,14 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.core import jaxcompat
+
+from ..common import pad_rows, row_tiling
 from . import ref
 from .logmem_update import logmem_admit_pallas
 
 NEG_BIG = -1e30
 PAD_ID = -1
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 @partial(jax.jit, static_argnames=("block_n", "use_pallas"))
@@ -48,8 +44,13 @@ def logmem_admit(scores, ids, tau, *, block_n: int = 512,
                  constant_values=PAD_ID)
     thr = tau.astype(jnp.float32)
     if use_pallas:
+        bm, mp = row_tiling(m)
         mask, acounts, lcounts, tmax = logmem_admit_pallas(
-            sp, ip, thr, block_n=bn, interpret=not _on_tpu())
+            pad_rows(sp, mp, NEG_BIG), pad_rows(ip, mp, PAD_ID),
+            pad_rows(thr, mp, 0.0), block_n=bn, block_m=bm,
+            interpret=jaxcompat.pallas_interpret())
+        mask, acounts, lcounts, tmax = (mask[:m], acounts[:m], lcounts[:m],
+                                        tmax[:m])
     else:
         mask, acounts, lcounts, tmax = ref.logmem_admit(sp, ip, thr, bn)
     return mask[:, :n], acounts, lcounts, tmax

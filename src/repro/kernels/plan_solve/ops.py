@@ -3,13 +3,13 @@
 ``enum_solve``/``dp_solve`` are traceable (call them inside ``jax.jit``):
 the combo tables and one-hot expansion matrices are static constants
 baked into the program. The Pallas kernel path covers the heavy joint
-enumeration (compiled on TPU, interpret elsewhere — correctness only);
+enumeration (compiled on TPU, interpret mode on CPU — correctness only);
 the default elsewhere is the pure-jnp reference, which XLA fuses into
 the surrounding solver program. The cheap DP reduction always runs as
 jnp.
 
 Float policy: the reduction runs in whatever dtype the term tensors
-carry — float64 under ``jax.experimental.enable_x64`` (the
+carry — float64 under ``jaxcompat.enable_x64`` (the
 oracle-matching CPU path), float32 on TPU where Pallas has no f64
 (documented in the README; plans then match the NumPy oracle within
 float32 tolerance, not ulps).
@@ -19,9 +19,10 @@ from __future__ import annotations
 import functools
 import itertools
 
-import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.core import jaxcompat
 
 from . import ref
 from .plan_solve import plan_solve_pallas
@@ -45,13 +46,6 @@ def _onehots(c: int, j: int, gp: int, dtype_name: str) -> np.ndarray:
     for jj in range(j):
         oh[jj, combos[:, jj], np.arange(g)] = 1.0
     return oh
-
-
-def on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - backend probing
-        return False
 
 
 def enum_solve(fs, consts, *, cand, kf=None, pair_caps=None, alpha=None,
@@ -107,7 +101,8 @@ def enum_solve(fs, consts, *, cand, kf=None, pair_caps=None, alpha=None,
     val, idx = plan_solve_pallas(
         _pad(fs), _pad(const_arr), _pad(cand), _pad(mask_grid),
         _pad(lb_grid), _pad(dl_grid), _pad(rb), onehot, g_real=g,
-        masked=masked, block_m=block_m, interpret=not on_tpu())
+        masked=masked, block_m=block_m,
+        interpret=jaxcompat.pallas_interpret())
     val, idx = val[:m], idx[:m]
     s_idx = idx // g
     sel = jnp.asarray(combos, jnp.int32)[idx % g]

@@ -1,12 +1,12 @@
 """Pure-jnp oracle for the fused plan-solve reduction.
 
 Solvers over one tier subset's sorted candidate grid (M, C). Every
-reduction is a running strict-< update over static column slices or a
-fast ``min`` reduce — the obvious formulations (``jnp.sort``,
-``jnp.take`` over a combo table, ``jnp.argmin``, scatter) all lower to
-serial scalar loops on XLA CPU and cost 10–50× the arithmetic they
-feed; on this backend wall-clock tracks the *operation count*, so the
-solvers are written to minimize materialized ops.
+reduction is a running strict-< update over static column slices or
+one variadic ``(value, key)`` minimum (``_lexmin``): the minimum and the
+key that attains it come out of the same reduction. (Testing equality
+against a separately reduced minimum is not safe: XLA may recompute the
+operand per consumer with different fma contraction, and then no entry
+equals the minimum.)
 
 * ``dp_arr`` — the monotone running-minimum DP
   (``core.shp._solve_unconstrained``): exact when no pairwise lower
@@ -36,26 +36,32 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-_BIG_I = np.int32(2 ** 30)
+
+def _lexmin(vals, keys, axis):
+    """Minimum of ``vals`` along ``axis`` and, among the entries that
+    attain it, the smallest ``key`` — one variadic reduction. NaN values
+    lose to every number (rows of NaN only return NaN)."""
+    def pick(a, b):
+        (va, ka), (vb, kb) = a, b
+        take_b = ((vb < va) | ((vb == va) & (kb < ka))
+                  | (jnp.isnan(va) & ~jnp.isnan(vb)))
+        return jnp.where(take_b, vb, va), jnp.where(take_b, kb, ka)
+
+    big = (jnp.inf if jnp.issubdtype(keys.dtype, jnp.floating)
+           else np.iinfo(keys.dtype).max)
+    init = (jnp.asarray(jnp.inf, vals.dtype), jnp.asarray(big, keys.dtype))
+    return jax.lax.reduce((vals, keys), init, pick, (axis % vals.ndim,))
 
 
 def first_argmin(x, axis=-1):
-    """(min, first index attaining it) without ``jnp.argmin`` (a scalar
-    loop on CPU): min + masked-iota min keeps the first-minimum-wins
-    tie-break. NaN rows return index 0 with the NaN min, which the
-    callers' strict-< folds then discard — the same outcome as the
-    host's NaN-discarding comparisons."""
-    x = jax.lax.optimization_barrier(x)  # pin one materialization: XLA
-    # may otherwise recompute x with different fma contraction in the
-    # min- and eq-consumers, so the minimum never "hits" its own value
-    vmin = jnp.min(x, axis=axis)
-    iota = jnp.arange(x.shape[axis], dtype=jnp.int32)
-    shape = [1] * x.ndim
-    shape[axis] = x.shape[axis]
-    hit = jnp.where(x == jnp.expand_dims(vmin, axis), iota.reshape(shape),
-                    _BIG_I)
-    amin = jnp.min(hit, axis=axis)
-    return vmin, jnp.where(amin == _BIG_I, 0, amin)
+    """(min, first index attaining it): first-minimum-wins tie-break.
+    NaN-poisoned rows return index 0 with a NaN min, which the callers'
+    strict-< folds then discard — the same outcome as the host's
+    NaN-discarding comparisons."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis % x.ndim)
+    vmin, amin = _lexmin(x, iota, axis)
+    poisoned = jnp.isnan(x).any(axis=axis)
+    return jnp.where(poisoned, jnp.nan, vmin), jnp.where(poisoned, 0, amin)
 
 
 def pick_col(x, idx):
@@ -115,13 +121,12 @@ def value_argmin(f, cand):
     """(min of f, boundary value attaining it) over an *unsorted* grid:
     among minimal-cost candidates the smallest boundary value wins —
     exactly the host's first-index tie-break on its value-sorted grid.
-    All-inf (or NaN-poisoned) rows return +inf values, which the
-    callers' strict-< folds discard."""
-    f = jax.lax.optimization_barrier(f)  # see first_argmin: pin one
-    # materialization so the eq-consumer sees the min's exact bits
-    vmin = jnp.min(f, axis=1)
-    bval = jnp.min(jnp.where(f == vmin[:, None], cand, jnp.inf), axis=1)
-    return vmin, bval
+    All-inf rows return a +inf minimum and NaN-poisoned rows a NaN one,
+    which the callers' strict-< folds discard."""
+    vmin, bval = _lexmin(f, cand, 1)
+    poisoned = jnp.isnan(f).any(axis=1)
+    return (jnp.where(poisoned, jnp.nan, vmin),
+            jnp.where(poisoned, jnp.inf, bval))
 
 
 def single_arr(f0, cand, *, alpha=None, rhs=None, atol=None):
@@ -147,14 +152,9 @@ def tri_arr(f0, f1, cand, *, kf=None, cap_m=None, alpha=None, rhs=None,
     assembled with the same adds as the host: f0 + f1. Returns
     (interior (M,), sel [c0, c1])."""
     c = cand.shape[1]
-    # pin one materialization of the inputs: the origin-recovery pass
-    # below matches f0 against the tracked minimum by equality, which
-    # only holds if XLA does not recompute f0 with different fma
-    # contraction in different consumers (see first_argmin)
-    f0, f1, cand = jax.lax.optimization_barrier((f0, f1, cand))
     budget_cap = (rhs + atol) if alpha is not None else None
     best = jnp.full(f0.shape[:1], jnp.inf, f0.dtype)
-    bm0 = jnp.full(best.shape, jnp.inf, f0.dtype)
+    bv0 = jnp.zeros(best.shape, f0.dtype)
     bv1 = jnp.zeros(best.shape, f0.dtype)
     for c1 in range(c):
         c1v = cand[:, c1]
@@ -165,25 +165,14 @@ def tri_arr(f0, f1, cand, *, kf=None, cap_m=None, alpha=None, rhs=None,
         if alpha is not None:
             acc = cand * alpha[0][:, None] + (c1v * alpha[1])[:, None]
             feas = feas & (acc <= budget_cap[:, None])
-        m0 = jnp.min(jnp.where(feas, f0, jnp.inf), axis=1)
+        # the best origin for this destination: the smallest candidate
+        # value attaining the feasible minimum
+        m0, v0 = _lexmin(jnp.where(feas, f0, jnp.inf), cand, 1)
         tot = m0 + f1[:, c1]
         upd = tot < best
         best = jnp.where(upd, tot, best)
-        bm0 = jnp.where(upd, m0, bm0)
+        bv0 = jnp.where(upd, v0, bv0)
         bv1 = jnp.where(upd, c1v, bv1)
-    # recover the winning origin in one pass: re-apply the winner's
-    # feasibility at destination bv1 and pick the smallest candidate
-    # value attaining the tracked origin minimum bm0
-    feas = cand <= bv1[:, None]
-    if cap_m is not None:
-        lbd = pair_lb_law(bv1, cap_m, kf) * (1 - 1e-12) - 1e-12
-        feas = feas & (cand >= lbd[:, None])
-    if alpha is not None:
-        acc = cand * alpha[0][:, None] + (bv1 * alpha[1])[:, None]
-        feas = feas & (acc <= budget_cap[:, None])
-    bv0 = jnp.min(jnp.where(feas & (f0 == bm0[:, None]), cand, jnp.inf),
-                  axis=1)
-    bv0 = jnp.where(jnp.isfinite(bv0), bv0, 0.0)
     return best, [bv0, bv1]
 
 

@@ -1,6 +1,6 @@
 """Public wrapper for the finalize-time tier assignment: quantize the
 float boundary vectors to exact integer thresholds, pad the survivor
-axis, run the 2-D kernel (interpret off-TPU), strip the padding.
+axes, run the 2-D kernel (interpret mode on CPU only), strip the padding.
 
 Boundary quantization: survivor ids are integers, so ``id >= b`` for a
 float boundary b is exactly ``id >= ceil(b)`` — the comparison the kernel
@@ -17,17 +17,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import jaxcompat
+
+from ..common import pad_rows, row_tiling
 from . import ref
 from .tier_assign import tier_assign_pallas
 
 _INT_MAX = np.iinfo(np.int32).max
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 def quantize_boundaries(bounds) -> np.ndarray:
@@ -46,9 +42,12 @@ def _assign(ids, bounds_int, floor, *, n_tiers, block_k, use_pallas):
     idp = jnp.pad(ids.astype(jnp.int32), ((0, 0), (0, pad)),
                   constant_values=-1)
     if use_pallas:
-        tier, counts = tier_assign_pallas(idp, bounds_int, floor,
-                                          n_tiers=n_tiers, block_k=bk,
-                                          interpret=not _on_tpu())
+        bm, mp = row_tiling(m)
+        tier, counts = tier_assign_pallas(
+            pad_rows(idp, mp, -1), pad_rows(bounds_int, mp, 0),
+            pad_rows(floor, mp, 0), n_tiers=n_tiers, block_k=bk,
+            block_m=bm, interpret=jaxcompat.pallas_interpret())
+        tier, counts = tier[:m], counts[:m]
     else:
         tier, counts = ref.tier_assign(idp, bounds_int, floor, n_tiers)
     return tier[:, :k], counts

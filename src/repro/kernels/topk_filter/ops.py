@@ -1,5 +1,6 @@
-"""Public wrapper: pad, run kernel (interpret off-TPU), and the composed
-``filter_then_merge`` used by the streaming reservoir at batch scale."""
+"""Public wrapper: pad, run kernel (interpret mode on CPU only), and the
+composed ``filter_then_merge`` used by the streaming reservoir at batch
+scale."""
 from __future__ import annotations
 
 from functools import partial
@@ -7,17 +8,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.core import jaxcompat
+
 from . import ref
 from .topk_filter import topk_filter_pallas
 
 NEG_BIG = -1e30
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 @partial(jax.jit, static_argnames=("block_n", "use_pallas"))
@@ -38,7 +34,8 @@ def topk_filter(scores, threshold, *, block_n: int = 4096,
     sp = jnp.where(jnp.isnan(sp), NEG_BIG, sp)
     if use_pallas:
         mask, counts, tmax = topk_filter_pallas(
-            sp, jnp.asarray(threshold), block_n=bn, interpret=not _on_tpu())
+            sp, jnp.asarray(threshold), block_n=bn,
+            interpret=jaxcompat.pallas_interpret())
     else:
         mask, counts, tmax = ref.topk_filter(sp, jnp.asarray(threshold), bn)
     return mask[:n], counts, tmax

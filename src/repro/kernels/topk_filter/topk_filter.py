@@ -8,26 +8,16 @@ candidates fail, the rare survivors go through the exact (tiny) merge in
 VMEM, emitting the survivor mask plus per-tile counts and maxima (the
 maxima let the host skip entire tiles on the next refinement pass).
 
-Grid: (N/bn,) — embarrassingly parallel, bandwidth-bound. The 2-D sibling
-``repro.kernels.batched_topk`` runs the same scan for M concurrent streams
-against per-stream bars (grid (M, N/bn)).
+It is the one-stream case of ``repro.kernels.batched_topk``: the vector
+is laid out as a (1, N) row, whose one-row blocks equal the array's own
+row count (TPU blocks take no rank-1 scalars), and the per-tile counts
+and maxima come back lane-dense in one (1, N/bn) row.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-
-def _kernel(scores_ref, thr_ref, mask_ref, count_ref, tmax_ref):
-    s = scores_ref[...].astype(jnp.float32)  # (bn,)
-    thr = thr_ref[0]
-    hit = s > thr
-    mask_ref[...] = hit.astype(jnp.int8)
-    count_ref[0] = hit.sum().astype(jnp.int32)
-    tmax_ref[0] = s.max()
+from ..batched_topk.batched_topk import batched_topk_pallas
 
 
 def topk_filter_pallas(scores, threshold, *, block_n: int = 4096,
@@ -35,25 +25,7 @@ def topk_filter_pallas(scores, threshold, *, block_n: int = 4096,
     """scores: (N,) float — threshold: () float32.
     Returns (mask (N,) int8, counts (N/bn,) int32, tile_max (N/bn,) f32)."""
     n = scores.shape[0]
-    assert n % block_n == 0, (n, block_n)
-    n_tiles = n // block_n
-    thr = jnp.reshape(threshold.astype(jnp.float32), (1,))
-    return pl.pallas_call(
-        _kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
-        out_shape=(
-            jax.ShapeDtypeStruct((n,), jnp.int8),
-            jax.ShapeDtypeStruct((n_tiles,), jnp.int32),
-            jax.ShapeDtypeStruct((n_tiles,), jnp.float32),
-        ),
-        interpret=interpret,
-    )(scores, thr)
+    mask, counts, tmax = batched_topk_pallas(
+        scores.reshape(1, n), jnp.reshape(threshold, (1,)), block_n=block_n,
+        block_m=1, interpret=interpret)
+    return mask[0], counts[0], tmax[0]

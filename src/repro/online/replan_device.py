@@ -29,22 +29,16 @@ import itertools
 
 import numpy as np
 
-try:
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
+import jax
+import jax.numpy as jnp
 
-    _HAVE_JAX = True
-except Exception:  # pragma: no cover - exercised only without jax
-    _HAVE_JAX = False
-
-from repro.core import shp, shp_jax
+from repro.core import jaxcompat, shp, shp_jax
 
 _MOVE_TOL = 1e-6  # == replan._MOVE_TOL
 
 
 def available(t: int) -> bool:
-    return _HAVE_JAX and 2 <= t <= shp_jax.MAX_DEVICE_TIERS
+    return 2 <= t <= shp_jax.MAX_DEVICE_TIERS
 
 
 def _w_suffix(x, n0, rho, k):
@@ -266,7 +260,7 @@ def _solve_impl(cw, cr, cs, n, k, rpw, cap, lat, slo, n0, rho, b0, *, t,
     return best_val, jnp.stack(best_bounds, axis=1), cost_old
 
 
-@functools.partial(jax.jit if _HAVE_JAX else lambda f, **kw: f,
+@functools.partial(jax.jit,
                    static_argnames=("t", "constrained", "capfin",
                                     "slo_any", "allow_moves"))
 def _solve_jit(cw, cr, cs, n, k, rpw, cap, lat, slo, n0, rho, b0, *, t,
@@ -286,9 +280,9 @@ def _solve_sharded_fn(mesh, t, constrained, capfin, slo_any, allow_moves):
                            capfin=capfin, slo_any=slo_any,
                            allow_moves=allow_moves)
     spec = fleet_mod.row_spec()
-    return jax.jit(fleet_mod.shard_map(
+    return jax.jit(jaxcompat.shard_map(
         fn, mesh=mesh, in_specs=(spec,) * 12,
-        out_specs=(spec, spec, spec), check_rep=False))
+        out_specs=(spec, spec, spec)))
 
 
 def solve_group(cw, cr, cs, n, k, rpw, cap, lat, slo, n0, rho, b0, *,
@@ -328,7 +322,7 @@ def solve_group(cw, cr, cs, n, k, rpw, cap, lat, slo, n0, rho, b0, *,
                               rho, b0)]
     # jit-cache probe (repro.obs.jits): one compiled signature per
     # (T, constraint-signature, padded-R) static key
-    with enable_x64():
+    with jaxcompat.enable_x64():
         if shards > 1:
             fn = _solve_sharded_fn(mesh, t, constrained, capfin, slo_any,
                                    bool(allow_moves))

@@ -31,10 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # newer jax exports shard_map at the top level
-    from jax import shard_map  # type: ignore
-except ImportError:  # pragma: no cover - version-dependent import path
-    from jax.experimental.shard_map import shard_map  # type: ignore
+from repro.core import jaxcompat
 
 FLEET_AXIS = "fleet"
 _STATE = threading.local()
@@ -143,8 +140,10 @@ def _waterfill_local(d, budget):
     ``min(d, lo)`` can never oversubscribe the budget (up to the psum's
     own fp summation, ~1 ulp — the property test's tolerance)."""
     total = jax.lax.psum(d.sum(), FLEET_AXIS)
-    hi0 = jax.lax.pmax(jnp.max(d, initial=jnp.zeros((), d.dtype)),
-                       FLEET_AXIS)
+    # the TPU lowers only sum all-reduces in f64: gather the per-shard
+    # maxima instead of a pmax
+    hi0 = jnp.max(jax.lax.all_gather(
+        jnp.max(d, initial=jnp.zeros((), d.dtype)), FLEET_AXIS))
 
     def body(_, lohi):
         lo, hi = lohi
@@ -165,10 +164,9 @@ _WF_CACHE: dict = {}
 def _waterfill_fn(mesh: Mesh):
     fn = _WF_CACHE.get(mesh)
     if fn is None:
-        fn = _WF_CACHE[mesh] = jax.jit(shard_map(
+        fn = _WF_CACHE[mesh] = jax.jit(jaxcompat.shard_map(
             _waterfill_local, mesh=mesh,
-            in_specs=(row_spec(), P()), out_specs=row_spec(),
-            check_rep=False))
+            in_specs=(row_spec(), P()), out_specs=row_spec()))
     return fn
 
 
@@ -183,14 +181,13 @@ def waterfill_sharded(desired, budget: float, mesh: Mesh) -> np.ndarray:
     ulp (bisecting from below guarantees the fleet never oversubscribes
     ``budget``; when the desires already fit they are granted verbatim).
     """
-    from jax.experimental import enable_x64
     d = np.asarray(desired, np.float64).reshape(-1)
     m = d.shape[0]
     shards = n_shards(mesh)
     mp = pad_rows(m, shards)
     dp = np.zeros(mp, np.float64)
     dp[:m] = d  # zero-desire pad rows draw no grant at any λ
-    with enable_x64():
+    with jaxcompat.enable_x64():
         out = _waterfill_fn(mesh)(
             jax.device_put(dp, row_sharding(mesh)),
             jax.device_put(jnp.asarray(float(budget), jnp.float64),

@@ -281,12 +281,11 @@ def _make_step(use_kernel_filter: bool, block_n: int, drift_cfg=None,
             tuple(new_dstates), mstate, tuple(new_cstates)
 
     if mesh is not None:
+        from repro.core import jaxcompat
         from repro.parallel import fleet
         spec = fleet.row_spec()
-        step = fleet.shard_map(step, mesh=mesh,
-                               in_specs=(spec,) * 5,
-                               out_specs=(spec,) * 6,
-                               check_rep=False)
+        step = jaxcompat.shard_map(step, mesh=mesh, in_specs=(spec,) * 5,
+                                   out_specs=(spec,) * 6)
     return jax.jit(step, donate_argnums=(0, 2, 3, 4) if donate else ())
 
 

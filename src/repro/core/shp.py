@@ -22,6 +22,8 @@ from typing import Literal, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs import timers
+
 from . import constraints as constraints_mod
 from .constraints import ConstraintSet, ReadLatencySLO, TierCapacity
 from .costs import NTierCostModel, TwoTierCostModel
@@ -1128,32 +1130,44 @@ def plan_placement_ntier(cm: NTierCostModel,
                               migrate=migrate, n_docs=wl.n_docs, t=cm.t)
 
 
-def plan_ntier_batch(models: Sequence[NTierCostModel], constraints=None):
-    """Vectorized plan for a batch of N-tier models sharing one T.
-    ``constraints`` is a shared ``ConstraintSet`` or one per model.
-    Returns (total (M,), bounds (M, T-1), migrate (M,), strategies list)."""
+def solve_ntier_batch(models: Sequence[NTierCostModel], constraints=None,
+                      *, tracer=None) -> dict:
+    """Vectorized plan for a batch of N-tier models sharing one T: pack
+    them into struct-of-arrays form (span ``plan.pack``) and solve them
+    in one call (``plan.solve``). ``constraints`` is a shared
+    ``ConstraintSet`` or one per model; spans go to ``tracer`` when
+    given. Returns ``plan_ntier_arrays``' dict (``total``, ``bounds``,
+    ``migrate``); ``ntier_strategies`` names its plans."""
     t = models[0].t
     if any(m.t != t for m in models):
-        raise ValueError("plan_ntier_batch needs a uniform tier count")
-    cw = np.stack([m.cw for m in models])
-    cr = np.stack([m.cr for m in models])
-    cs = np.stack([m.cs for m in models])
-    n = np.array([float(m.workload.n_docs) for m in models])
-    k = np.array([float(m.workload.k) for m in models])
-    rpw = np.array([m.workload.reads_per_window for m in models])
-    per_model = (constraints if isinstance(constraints, (list, tuple))
-                 else [constraints] * len(models))
-    compiled = [resolve_constraints(m, c)
-                for m, c in zip(models, per_model)]
-    cap = np.stack([c[0] for c in compiled])
-    lat = np.stack([c[1] for c in compiled])
-    slo = np.array([c[2] for c in compiled])
-    out = plan_ntier_arrays(cw, cr, cs, n, k, rpw, cap=cap, lat=lat, slo=slo)
-    strategies = [("infeasible" if not np.isfinite(out["total"][i])
-                   else ntier_strategy_name(out["bounds"][i], n[i], t,
-                                            bool(out["migrate"][i])))
-                  for i in range(len(models))]
-    return out["total"], out["bounds"], out["migrate"], strategies
+        raise ValueError("solve_ntier_batch needs a uniform tier count")
+    with timers.span("plan.pack", tracer, tenants=len(models)):
+        cw = np.stack([m.cw for m in models])
+        cr = np.stack([m.cr for m in models])
+        cs = np.stack([m.cs for m in models])
+        n = np.array([float(m.workload.n_docs) for m in models])
+        k = np.array([float(m.workload.k) for m in models])
+        rpw = np.array([m.workload.reads_per_window for m in models])
+        per_model = (constraints if isinstance(constraints, (list, tuple))
+                     else [constraints] * len(models))
+        compiled = [resolve_constraints(m, c)
+                    for m, c in zip(models, per_model)]
+        cap = np.stack([c[0] for c in compiled])
+        lat = np.stack([c[1] for c in compiled])
+        slo = np.array([c[2] for c in compiled])
+    with timers.span("plan.solve", tracer):
+        return plan_ntier_arrays(cw, cr, cs, n, k, rpw, cap=cap, lat=lat,
+                                 slo=slo)
+
+
+def ntier_strategies(out: dict, models: Sequence[NTierCostModel]) -> list:
+    """Strategy name per plan of a ``solve_ntier_batch`` result."""
+    t = models[0].t
+    return [("infeasible" if not np.isfinite(out["total"][i])
+             else ntier_strategy_name(out["bounds"][i],
+                                      float(m.workload.n_docs), t,
+                                      bool(out["migrate"][i])))
+            for i, m in enumerate(models)]
 
 
 def brute_force_plan_ntier(cm: NTierCostModel, grid: int = 48,

@@ -34,6 +34,7 @@ def init(k: int) -> ReservoirState:
     )
 
 
+@jax.named_scope("member")
 def member(needles: jax.Array, haystack: jax.Array) -> jax.Array:
     """Boolean membership mask (``needles[i] in haystack``) via
     sort + binary search — O((H+N)·log H) instead of ``jnp.isin``'s

@@ -10,12 +10,22 @@
 #   costs     — device-side CostState ledger + closed-form expected-cost
 #               trajectories, per-tenant regret, and budget burn-rate
 #               alerts (CostMonitor)
-#   trace     — span/event timeline with a stable JSONL schema and
-#               jax.profiler TraceAnnotation integration
+#   trace     — span/event timeline with a stable JSONL schema (each
+#               span names its parent) and jax.profiler TraceAnnotation
+#               integration
 #   jits      — jit-cache hit/miss + compile-time probes (shp_jax,
-#               replan_device)
-#   timers    — the shared benchmark/evaluation timing API
+#               replan_device, the engine's fleet step)
+#   timers    — the shared timing API and the one span helper: into a
+#               Tracer when there is one, else a bare profiler
+#               annotation; each name's newest durations, process-wide
 #   export    — Prometheus text exposition + JSON snapshots
+#
+# Spans the program opens: plan / ingest / replan / finalize (engine
+# Tracer); per ingest chunk ingest.route, ingest.stage, ingest.dispatch,
+# ingest.wait, ingest.fetch, ingest.meter, ingest.monitors,
+# ingest.checkpoint (engine); per planner pass plan.pack, plan.solve,
+# plan.unpack (core.shp / streams.planner). Named device scopes in the
+# jitted fleet step: filter, merge, member, evicted, logmem, drift, obs.
 """Fleet observability: configuration and the per-run facade.
 
 ``Observability`` is the object callers thread through the system::
@@ -61,8 +71,13 @@ class ObsConfig:
     planned on both windows of a ``burn_windows`` (long, short,
     threshold) pair. ``events_path``: stream the event log to this
     JSONL file. ``profiler_annotations``: mirror spans into the JAX
-    profiler timeline. ``trace_ingest``: record a span per ingest chunk
-    (point events for replan/admission/violations are always recorded).
+    profiler timeline. ``trace_ingest``: record the per-chunk ingest
+    spans — ``ingest`` around a routed batch, and for every chunk
+    ``ingest.route`` (routed batches), ``ingest.stage``,
+    ``ingest.dispatch``, ``ingest.wait``, ``ingest.fetch``,
+    ``ingest.meter``, ``ingest.monitors`` and ``ingest.checkpoint``
+    (with a checkpoint hook), each tagged with its ``chunk`` (point
+    events for replan/admission/violations are always recorded).
     """
 
     metrics: bool = True

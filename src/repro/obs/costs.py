@@ -120,6 +120,7 @@ def _per_tier(tiers, mask, n_tiers: int):
                       for t in range(n_tiers)], axis=1)
 
 
+@jax.named_scope("obs")
 def accumulate_exact(cs: CostState, batch_ids, wrote, evicted_ids,
                      state_ids) -> CostState:
     """Fold one exact-backend bucket step into the ledger (pure; traced
@@ -138,6 +139,7 @@ def accumulate_exact(cs: CostState, batch_ids, wrote, evicted_ids,
                        + occ * docs[:, None])
 
 
+@jax.named_scope("obs")
 def accumulate_logmem(cs: CostState, batch_ids, wrote) -> CostState:
     """Logmem-bucket step: no ids stored and nothing deletes, so
     occupancy ≡ cumulative writes per tier and the storage integral

@@ -28,6 +28,7 @@ _COUNTER_LEAVES = frozenset({
     "checks", "steps", "hits", "misses", "compiles",
     "scores_quarantined", "chunks_ingested", "checkpoints_written",
     "redeliveries_dropped", "delivery_retries", "tier_outages",
+    "route_slots", "route_useful",
 })
 
 # HELP text per terminal path component (kept to the metrics whose
@@ -61,6 +62,8 @@ _HELP = {
                             "idempotent redelivery guard",
     "delivery_retries": "transient chunk-delivery failures retried",
     "tier_outages": "tier outage declarations (cumulative)",
+    "route_slots": "dense slots the router emitted (documents + padding)",
+    "route_useful": "routed slots holding a document",
 }
 
 

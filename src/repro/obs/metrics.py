@@ -74,6 +74,7 @@ def shard_pack(ms: MetricsState) -> MetricsState:
                         drift_score_max=ms.drift_score_max[None])
 
 
+@jax.named_scope("obs")
 def accumulate_bucket(ms: MetricsState, batch_scores, batch_ids, bar,
                       wrote, evicted) -> MetricsState:
     """Fold one bucket's step outputs into the counters (pure; traced
@@ -95,6 +96,7 @@ def accumulate_bucket(ms: MetricsState, batch_scores, batch_ids, bar,
     return ms._replace(counts=ms.counts + delta)
 
 
+@jax.named_scope("obs")
 def accumulate_quarantine(ms: MetricsState, count) -> MetricsState:
     """Count non-finite scores the step swapped out for pad slots before
     they could poison the reservoir compares (NaN fails every compare)."""
@@ -102,6 +104,7 @@ def accumulate_quarantine(ms: MetricsState, count) -> MetricsState:
         jnp.asarray(count, jnp.int32)))
 
 
+@jax.named_scope("obs")
 def accumulate_drift(ms: MetricsState, score_max, fired_count
                      ) -> MetricsState:
     """Fold the drift detector's per-step summary (max normalized score,
@@ -114,6 +117,7 @@ def accumulate_drift(ms: MetricsState, score_max, fired_count
                                     jnp.asarray(score_max, jnp.float32)))
 
 
+@jax.named_scope("obs")
 def bump_chunk(ms: MetricsState) -> MetricsState:
     return ms._replace(counts=ms.counts.at[CHUNKS].add(1))
 

@@ -13,6 +13,10 @@ Every record is one JSON object with a stable schema (``SCHEMA``):
     {"v": 1, "kind": "span"|"event", "name": str, "ts": unix seconds,
      "dur_s": float|null, "attrs": {...}}
 
+A span record also carries ``"parent"``: the name of the span of the same
+tracer and thread that encloses it, or null at the top level (a key
+added within schema v1; readers of v1 records treat it as optional).
+
 Records are kept in a bounded in-memory deque (``max_events``, oldest
 dropped) and, when ``path`` is given, streamed to a JSONL file as they
 complete — a long-running fleet never grows host memory without bound
@@ -22,6 +26,7 @@ JSON-serializable scalars/lists; numpy scalars are coerced.
 from __future__ import annotations
 
 import json
+import threading
 import time
 from collections import deque
 from contextlib import contextmanager
@@ -62,6 +67,7 @@ class Tracer:
         self._path = path
         self._fh = None
         self.dropped = 0  # records evicted from the in-memory deque
+        self._open = threading.local()  # per-thread stack of open spans
 
     # ---- recording ------------------------------------------------------
 
@@ -88,15 +94,23 @@ class Tracer:
         """Record one named interval; yields the (mutable) attrs dict so
         the body can attach results before the span closes."""
         out = {k: _coerce(v) for k, v in attrs.items()}
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        parent = stack[-1] if stack else None
+        stack.append(str(name))
         ts = time.time()
         t0 = time.perf_counter()
-        if self.annotations:
-            with _TraceAnnotation(str(name)):
+        try:
+            if self.annotations:
+                with _TraceAnnotation(str(name)):
+                    yield out
+            else:
                 yield out
-        else:
-            yield out
+        finally:
+            stack.pop()
         self._record({"v": 1, "kind": "span", "name": str(name), "ts": ts,
-                      "dur_s": time.perf_counter() - t0,
+                      "dur_s": time.perf_counter() - t0, "parent": parent,
                       "attrs": {k: _coerce(v) for k, v in out.items()}})
 
     # ---- draining -------------------------------------------------------

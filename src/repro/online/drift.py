@@ -142,6 +142,7 @@ def _budget_overrun(checks, cfg: DriftConfig):
     return 2.0 * jnp.log(over)
 
 
+@jax.named_scope("drift")
 def update(state: DriftState, wrote_count, seen_after,
            k, cfg: DriftConfig, slack: float = 0.0) -> DriftState:
     """One chunk of evidence per stream (jit-friendly, (M,) batched).
@@ -245,6 +246,7 @@ def anchor_seen(state: DriftState) -> jax.Array:
     return jnp.where(jnp.maximum(s_pos, s_neg) >= 1.0, anchor, state.seen)
 
 
+@jax.named_scope("drift")
 def scores(state: DriftState, cfg: DriftConfig,
            slack: float = 0.0) -> jax.Array:
     """(M,) normalized change score: the largest of the three test

@@ -20,6 +20,8 @@ freely inside one fleet step.
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 from dataclasses import dataclass
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -29,6 +31,7 @@ import numpy as np
 
 from repro.core import topk
 from repro.core.costs import NTierCostModel, TwoTierCostModel
+from repro.obs import jits, timers
 
 from . import logmem, metering, planner, router
 
@@ -56,6 +59,7 @@ def _as_single(state: BatchedReservoirState) -> topk.ReservoirState:
                                seen=state.seen)
 
 
+@jax.named_scope("merge")
 def update(state: BatchedReservoirState, batch_scores: jax.Array,
            batch_ids: jax.Array) -> Tuple[BatchedReservoirState, jax.Array]:
     """Fused update of all M streams: scores/ids (M, W), padding = (-inf, -1).
@@ -85,26 +89,29 @@ def filtered_update(state: BatchedReservoirState, batch_scores: jax.Array,
     k = state.scores.shape[1]
     w = batch_scores.shape[1]
     bar = state.scores[:, -1]
-    mask, _, _ = btk_ops.batched_topk_filter(batch_scores, bar,
-                                             block_n=block_n,
-                                             use_pallas=use_pallas)
-    # re-observed resident ids are dropped by topk.update anyway; mask them
-    # out *before* top_k so they cannot occupy a survivor slot that a fresh
-    # candidate (which plain ``update`` would admit) should get
     batch_ids = batch_ids.astype(jnp.int32)
-    resident = jax.vmap(topk.member)(batch_ids, state.ids)
-    keep = (mask > 0) & ~resident
-    surv = jnp.where(keep, batch_scores.astype(jnp.float32), -jnp.inf)
-    top_scores, top_idx = jax.lax.top_k(surv, min(k, w))
-    top_ids = jnp.take_along_axis(batch_ids, top_idx, axis=1)
-    top_ids = jnp.where(jnp.isfinite(top_scores), top_ids, PAD_ID)
-    new, wrote_top = jax.vmap(topk.update)(_as_single(state), top_scores,
-                                           top_ids)
-    # scatter the survivors' write mask back to batch positions
-    wrote = jnp.zeros(batch_scores.shape, bool)
-    rows = jnp.arange(batch_scores.shape[0])[:, None]
-    wrote = wrote.at[rows, top_idx].set(wrote_top)
-    wrote = wrote & (batch_ids >= 0)
+    with jax.named_scope("filter"):
+        mask, _, _ = btk_ops.batched_topk_filter(batch_scores, bar,
+                                                 block_n=block_n,
+                                                 use_pallas=use_pallas)
+        # re-observed resident ids are dropped by topk.update anyway; mask
+        # them out *before* top_k so they cannot occupy a survivor slot
+        # that a fresh candidate (which plain ``update`` would admit)
+        # should get
+        resident = jax.vmap(topk.member)(batch_ids, state.ids)
+        keep = (mask > 0) & ~resident
+        surv = jnp.where(keep, batch_scores.astype(jnp.float32), -jnp.inf)
+        top_scores, top_idx = jax.lax.top_k(surv, min(k, w))
+        top_ids = jnp.take_along_axis(batch_ids, top_idx, axis=1)
+        top_ids = jnp.where(jnp.isfinite(top_scores), top_ids, PAD_ID)
+    with jax.named_scope("merge"):
+        new, wrote_top = jax.vmap(topk.update)(_as_single(state),
+                                               top_scores, top_ids)
+        # scatter the survivors' write mask back to batch positions
+        wrote = jnp.zeros(batch_scores.shape, bool)
+        rows = jnp.arange(batch_scores.shape[0])[:, None]
+        wrote = wrote.at[rows, top_idx].set(wrote_top)
+        wrote = wrote & (batch_ids >= 0)
     seen = state.seen + (batch_ids >= 0).sum(axis=1).astype(state.seen.dtype)
     return BatchedReservoirState(new.scores, new.ids, seen), wrote
 
@@ -133,6 +140,7 @@ def placements(state: BatchedReservoirState, r) -> jax.Array:
     return jnp.where(state.ids >= 0, t, -1)
 
 
+@jax.named_scope("evicted")
 def evicted_ids(old: BatchedReservoirState,
                 new: BatchedReservoirState) -> jax.Array:
     """(M, K) local doc ids evicted by the step (-1 = none) — the storage
@@ -141,6 +149,7 @@ def evicted_ids(old: BatchedReservoirState,
     return jnp.where(ev, old.ids, PAD_ID)
 
 
+@functools.lru_cache(maxsize=64)
 def _make_step(use_kernel_filter: bool, block_n: int, drift_cfg=None,
                bucket_ks: Tuple[int, ...] = (), update_path: str = "auto",
                with_metrics: bool = False, mesh=None, donate: bool = False,
@@ -194,6 +203,9 @@ def _make_step(use_kernel_filter: bool, block_n: int, drift_cfg=None,
     chunk's state/drift/metrics buffers are donated to XLA, letting the
     outputs reuse them while the next chunk's host→device copy is in
     flight (``StreamEngine.ingest_chunks``).
+
+    Steps are cached by their configuration, so engines built alike
+    share one jitted step and a second one traces and compiles nothing.
     """
     if drift_cfg is not None:
         from repro.online import drift as drift_mod
@@ -418,7 +430,8 @@ class StreamEngine:
                 with self._tracer.span("plan", streams=len(planned)):
                     plan = planner.plan_fleet_mixed(
                         [s.cost_model for s in planned],
-                        constraints=constraints, mesh=mesh)
+                        constraints=constraints, mesh=mesh,
+                        tracer=self._tracer)
             else:
                 plan = planner.plan_fleet_mixed(
                     [s.cost_model for s in planned],
@@ -570,6 +583,9 @@ class StreamEngine:
             with_costs=self._cost_states is not None)
         self._step = self._step_factory(False)
         self._donating_step = None  # built lazily by ingest_chunks
+        # every dispatch is counted per (bucket widths, donate) signature,
+        # so a recompile of the step is named in the jit snapshot
+        self._step_probe = jits.probe("streams.engine.step")
         # resilience (repro.resilience): the ingest cursor is the chunk
         # sequence number — checkpoint step, and the idempotent
         # redelivery guard's high-water mark; a checkpointer attached via
@@ -608,28 +624,47 @@ class StreamEngine:
             self._ingest(stream_ids, scores, doc_ids, pad_to)
 
     def _ingest(self, stream_ids, scores, doc_ids, pad_to) -> None:
-        routed = self.router.route(stream_ids, scores, doc_ids, pad_to=pad_to)
+        r = self.router
+        slots, useful = r.route_slots, r.route_useful
+        with self._span("ingest.route") as sp:
+            routed = r.route(stream_ids, scores, doc_ids, pad_to=pad_to)
+            sp.attrs["slots"] = r.route_slots - slots
+            sp.attrs["useful"] = r.route_useful - useful
         self._run_chunk(routed)
 
-    def _stage_batches(self, dense) -> tuple:
+    def _span(self, name: str, chunk: Optional[int] = None, **attrs):
+        """One per-chunk ingest span (``ingest.*``): recorded by the
+        engine's tracer, or a bare ``timers.span`` on an engine without
+        one; nothing at all when ``ObsConfig.trace_ingest`` is off.
+        ``chunk`` defaults to the chunk being consumed."""
+        if self._obs is not None and not self._obs.config.trace_ingest:
+            return contextlib.nullcontext(timers.Span(name))
+        return timers.span(name, self._tracer,
+                           chunk=(self.chunks_ingested if chunk is None
+                                  else chunk), **attrs)
+
+    def _stage_batches(self, dense, chunk: Optional[int] = None) -> tuple:
         """Host dense per-bucket (scores, ids) pairs → device batches:
         plain ``jnp.asarray`` single-device, or row-padded + fleet-
         sharded ``device_put`` under a mesh (the transfer is async, which
-        is what ``ingest_chunks`` overlaps with the previous compute)."""
-        if self.mesh is None:
-            return tuple((jnp.asarray(s), jnp.asarray(i))
-                         for s, i in dense)
-        from repro.parallel import fleet
-        sh = fleet.row_sharding(self.mesh)
-        out = []
-        for bi, (s, i) in enumerate(dense):
-            pad = self._pad_m[bi] - s.shape[0]
-            if pad:
-                ps, pi = router.blank_dense(pad, s.shape[1])
-                s = np.concatenate([s, ps])
-                i = np.concatenate([i, pi])
-            out.append((jax.device_put(s, sh), jax.device_put(i, sh)))
-        return tuple(out)
+        is what ``ingest_chunks`` overlaps with the previous compute).
+        ``chunk`` is the index of the chunk staged (its span's tag)."""
+        nbytes = sum(getattr(a, "nbytes", 0) for pair in dense for a in pair)
+        with self._span("ingest.stage", chunk, bytes=nbytes):
+            if self.mesh is None:
+                return tuple((jnp.asarray(s), jnp.asarray(i))
+                             for s, i in dense)
+            from repro.parallel import fleet
+            sh = fleet.row_sharding(self.mesh)
+            out = []
+            for bi, (s, i) in enumerate(dense):
+                pad = self._pad_m[bi] - s.shape[0]
+                if pad:
+                    ps, pi = router.blank_dense(pad, s.shape[1])
+                    s = np.concatenate([s, ps])
+                    i = np.concatenate([i, pi])
+                out.append((jax.device_put(s, sh), jax.device_put(i, sh)))
+            return tuple(out)
 
     def _dispatch(self, batches, donate: bool):
         """Run one (already staged) fleet step and swap in the new
@@ -647,8 +682,11 @@ class StreamEngine:
             step = self._donating_step
         else:
             step = self._step
-        new_states, wrotes, evs, new_dstates, mstate, new_cstates = step(
-            tuple(self._states), batches, dstates, mstate, cstates)
+        key = (tuple(int(s.shape[1]) for s, _ in batches), donate)
+        with self._span("ingest.dispatch"):
+            new_states, wrotes, evs, new_dstates, mstate, new_cstates = \
+                self._step_probe.track(step, tuple(self._states), batches,
+                                       dstates, mstate, cstates, key=key)
         self._states = list(new_states)
         if self._metrics_state is not None:
             self._metrics_state = mstate
@@ -660,31 +698,47 @@ class StreamEngine:
 
     def _consume(self, dense, wrotes, evs, new_states,
                  meter: bool = True) -> None:
-        """Host side of one step: meter the transactions (slicing any
-        sharded padding back off), drain residuals, maybe re-plan."""
-        if meter:
-            for bi in range(len(self.buckets)):
-                b = self.buckets[bi]
-                mb = b.m
-                dense_scores, dense_ids = dense[bi]
-                # mirror the device quarantine: docs whose score is
-                # non-finite were demoted to pad slots in the step, so
-                # the host meter must not count them as observed either
-                if not np.isfinite(dense_scores).all():
-                    dense_ids = np.where(np.isfinite(dense_scores),
-                                         dense_ids, router.PAD_ID)
-                # logmem buckets have no resident ids: no cascade check,
-                # and their (mb, 0) eviction set scatters nothing
-                st_ids = (None if b.engine == "logmem"
-                          else np.asarray(new_states[bi].ids)[:mb])
-                self.meter.record_update(
-                    self._global_rows[bi], dense_ids,
-                    np.asarray(wrotes[bi])[:mb],
-                    np.asarray(evs[bi])[:mb], st_ids)
+        """Host side of one step: wait for its outputs, copy what the
+        meter needs to the host (slicing any sharded padding back off),
+        meter the transactions, drain residuals, maybe re-plan."""
+        if not meter:
+            return
+        # mirror the device quarantine: docs whose score is non-finite
+        # were demoted to pad slots in the step, so the host meter must
+        # not count them as observed either (host-only work, done while
+        # the step may still run on the device)
+        dense_ids = [i if np.isfinite(s).all()
+                     else np.where(np.isfinite(s), i, router.PAD_ID)
+                     for s, i in dense]
+        with self._span("ingest.wait"):
+            jax.block_until_ready((wrotes, evs, new_states))
+        with self._span("ingest.fetch") as sp:
+            # logmem buckets have no resident ids: no cascade check, and
+            # their (mb, 0) eviction set scatters nothing
+            fetched = [(np.asarray(wrotes[bi])[:b.m],
+                        np.asarray(evs[bi])[:b.m],
+                        None if b.engine == "logmem"
+                        else np.asarray(new_states[bi].ids)[:b.m])
+                       for bi, b in enumerate(self.buckets)]
+            sp.attrs["bytes"] = sum(a.nbytes for f in fetched for a in f
+                                    if a is not None)
+        with self._span("ingest.meter"):
+            for bi, (wrote, ev, st_ids) in enumerate(fetched):
+                self.meter.record_update(self._global_rows[bi],
+                                         dense_ids[bi], wrote, ev, st_ids)
+        with self._span("ingest.monitors"):
+            residual_rows, cost_rows = self._update_monitors()
+        if self._drift_states is not None:
+            self._maybe_replan(residual_rows, cost_rows)
+
+    def _update_monitors(self) -> Tuple[tuple, tuple]:
+        """Chunk-boundary drain of the residual and cost alert channels
+        from the meter; returns the rows each channel flags for re-plan
+        (empty unless it is configured as a trigger)."""
         residual_rows = ()
-        if meter and self._residuals is not None:
-            # chunk-boundary drain: the alert channel tests the meter's
-            # cumulative write residual against its concentration bound
+        if self._residuals is not None:
+            # the alert channel tests the meter's cumulative write
+            # residual against its concentration bound
             newly = self._residuals.update(self.meter.observed,
                                            self.meter.writes.sum(1))
             if newly.any() and self._tracer is not None:
@@ -700,7 +754,7 @@ class StreamEngine:
                 residual_rows = tuple(
                     int(r) for r in np.flatnonzero(self._residuals.alerted))
         cost_rows = ()
-        if meter and self._cost_monitor is not None:
+        if self._cost_monitor is not None:
             # the cost channel runs off the same meter drain: realized
             # spend vs the closed-form expected-cost trajectory
             newly_cost, newly_burn = self._cost_monitor.update(
@@ -733,8 +787,7 @@ class StreamEngine:
                 cost_rows = tuple(int(r) for r in np.flatnonzero(
                     self._cost_monitor.alerted
                     | self._cost_monitor.burn_alerted))
-        if meter and self._drift_states is not None:
-            self._maybe_replan(residual_rows, cost_rows)
+        return residual_rows, cost_rows
 
     def _run_chunk(self, dense, *, meter: bool = True,
                    donate: bool = False) -> None:
@@ -748,9 +801,11 @@ class StreamEngine:
         checkpoint hook (device buffers are final here and the next
         chunk has not been dispatched, so a snapshot is consistent and
         its device→host copies cannot race a donation)."""
+        chunk = self.chunks_ingested
         self.chunks_ingested += 1
         if self._checkpoint is not None:
-            self._checkpoint.on_chunk(self)
+            with self._span("ingest.checkpoint", chunk):
+                self._checkpoint.on_chunk(self)
 
     def attach_checkpointer(self, checkpointer) -> None:
         """Install a chunk-boundary checkpoint hook (an object with
@@ -800,7 +855,8 @@ class StreamEngine:
             # dispatch is async: the step runs while we stage chunk t+1
             wrotes, evs, new_states = self._dispatch(staged, donate=True)
             nxt = next(it, None)
-            staged = self._stage_batches(nxt) if nxt is not None else None
+            staged = (self._stage_batches(nxt, self.chunks_ingested + 1)
+                      if nxt is not None else None)
             # host consumption blocks on chunk t's outputs last
             self._consume(dense, wrotes, evs, new_states, meter=meter)
             # chunk-boundary checkpoint: the device→host copies read
@@ -1241,7 +1297,9 @@ class StreamEngine:
         from repro.obs import residuals as res_mod
         out: Dict = {"fleet": {"m": self.m, "buckets": len(self.buckets),
                                "logmem_streams":
-                                   int(self.meter.logmem.sum())}}
+                                   int(self.meter.logmem.sum())},
+                     "router": {"route_slots": self.router.route_slots,
+                                "route_useful": self.router.route_useful}}
         if self._metrics_state is not None:
             from repro.obs import metrics as metrics_mod
             out["engine"] = metrics_mod.snapshot(self._metrics_state)

@@ -130,6 +130,7 @@ def exact_bytes_per_stream(k: int) -> float:
     return 8.0 * k + 4.0
 
 
+@jax.named_scope("logmem")
 def update(state: LogmemState, batch_scores: jax.Array,
            batch_ids: jax.Array, k: int, *, block_n: int = 512,
            use_pallas: bool = True) -> Tuple[LogmemState, jax.Array]:

@@ -34,6 +34,7 @@ from repro.core import constraints as constraints_mod, shp
 from repro.core.constraints import ConstraintSet, TierCapacity
 from repro.core.costs import NTierCostModel, TwoTierCostModel
 from repro.core.placement import Policy
+from repro.obs import timers
 
 # Column order = candidate order in shp.plan_placement (ties resolve the
 # same way: first minimum wins).
@@ -317,28 +318,33 @@ def _as_ntier_models(models) -> List[NTierCostModel]:
 
 
 def _plan_mixed_ntier(nt_models, csets, boundaries, migrate,
-                      strategies, totals, only=None) -> None:
+                      strategies, totals, only=None, tracer=None) -> None:
     """One N-tier pass per distinct tier count (constrained when the
-    per-stream sets say so), writing the per-stream results in place.
-    ``only`` restricts to a subset of stream indices (the unconstrained
-    route's N-tier leg, and the water-filling re-plan)."""
+    per-stream sets say so), writing the per-stream results in place
+    (span ``plan.unpack``). ``only`` restricts to a subset of stream
+    indices (the unconstrained route's N-tier leg, and the water-filling
+    re-plan)."""
     by_t: dict = {}
     idx_iter = range(len(nt_models)) if only is None else only
     for i in idx_iter:
         by_t.setdefault(nt_models[i].t, []).append(i)
     for t, idxs in sorted(by_t.items()):
-        tot, bounds, mig, strats = shp.plan_ntier_batch(
-            [nt_models[i] for i in idxs],
-            constraints=[csets[i] for i in idxs])
-        for j, i in enumerate(idxs):
-            boundaries[i] = tuple(float(b) for b in bounds[j])
-            migrate[i] = bool(mig[j])
-            strategies[i] = strats[j]
-            totals[i] = tot[j]
+        group = [nt_models[i] for i in idxs]
+        out = shp.solve_ntier_batch(group,
+                                    constraints=[csets[i] for i in idxs],
+                                    tracer=tracer)
+        with timers.span("plan.unpack", tracer):
+            strats = shp.ntier_strategies(out, group)
+            for j, i in enumerate(idxs):
+                boundaries[i] = tuple(float(b) for b in out["bounds"][j])
+                migrate[i] = bool(out["migrate"][j])
+                strategies[i] = strats[j]
+                totals[i] = out["total"][j]
 
 
 def plan_fleet_mixed(models: Sequence[TwoTierCostModel | NTierCostModel],
-                     constraints=None, *, mesh=None) -> MixedFleetPlan:
+                     constraints=None, *, mesh=None,
+                     tracer=None) -> MixedFleetPlan:
     """Plan a heterogeneous fleet in a handful of vectorized passes: one
     legacy two-tier pass plus one N-tier pass per distinct tier count.
 
@@ -354,13 +360,16 @@ def plan_fleet_mixed(models: Sequence[TwoTierCostModel | NTierCostModel],
     for the duration of the call: the device N-tier solves dispatch per
     shard and the water-filling λ is found by cross-shard ``psum``
     bisection instead of the single-host scan.
+
+    Each N-tier pass opens the spans ``plan.pack``, ``plan.solve`` and
+    ``plan.unpack`` (``obs.timers.span``), into ``tracer`` when given.
     """
     if mesh is not None:
         from repro.parallel import fleet
         if fleet.get_fleet_mesh() is not mesh:
             with fleet.use_fleet_mesh(mesh):
                 return plan_fleet_mixed(models, constraints=constraints,
-                                        mesh=mesh)
+                                        mesh=mesh, tracer=tracer)
     m = len(models)
     boundaries: List[Tuple[float, ...]] = [()] * m
     migrate = np.zeros(m, bool)
@@ -402,7 +411,7 @@ def plan_fleet_mixed(models: Sequence[TwoTierCostModel | NTierCostModel],
                 raise TypeError(
                     f"stream {i}: unsupported cost model {type(cm)}")
         _plan_mixed_ntier(models, [None] * m, boundaries, migrate,
-                          strategies, totals, only=ntier_idx)
+                          strategies, totals, only=ntier_idx, tracer=tracer)
         return MixedFleetPlan(boundaries=tuple(boundaries),
                               migrate_flags=migrate,
                               strategies=tuple(strategies), totals=totals)
@@ -410,7 +419,7 @@ def plan_fleet_mixed(models: Sequence[TwoTierCostModel | NTierCostModel],
     nt_models = _as_ntier_models(models)
     csets = list(per_stream)
     _plan_mixed_ntier(nt_models, csets, boundaries, migrate,
-                      strategies, totals)
+                      strategies, totals, tracer=tracer)
     done_tiers: List[int] = []
     for cap_c in sorted(shared, key=lambda c: c.tier):
         if cap_c.max_bytes is not None:
@@ -443,7 +452,8 @@ def plan_fleet_mixed(models: Sequence[TwoTierCostModel | NTierCostModel],
                       for t in done_tiers]
             csets[i] = ConstraintSet(*csets[i], *extra)
         _plan_mixed_ntier(nt_models, csets, boundaries, migrate,
-                          strategies, totals, only=list(binding))
+                          strategies, totals, only=list(binding),
+                          tracer=tracer)
         done_tiers.append(cap_c.tier)
     return MixedFleetPlan(boundaries=tuple(boundaries),
                           migrate_flags=migrate,

@@ -71,10 +71,16 @@ def blank_dense(m: int, w: int) -> Tuple[np.ndarray, np.ndarray]:
 
 
 class StreamRouter:
-    """Routes mixed batches to bucket-dense matrices (numpy, host-side)."""
+    """Routes mixed batches to bucket-dense matrices (numpy, host-side).
+
+    Counts, over every ``route`` call, the dense slots it emitted
+    (``route_slots``) and the documents among them (``route_useful``):
+    the rest is padding to each bucket's power-of-two width."""
 
     def __init__(self, buckets: Sequence[Bucket]):
         self.buckets = list(buckets)
+        self.route_slots = 0
+        self.route_useful = 0
         sids, bis, rows = [], [], []
         for bi, b in enumerate(self.buckets):
             for row, sid in enumerate(b.stream_ids):
@@ -142,4 +148,6 @@ class StreamRouter:
             dense_s[rs, pos] = scores[sel][order]
             dense_i[rs, pos] = doc_ids[sel][order]
             out.append((dense_s, dense_i))
+        self.route_slots += sum(i.size for _, i in out)
+        self.route_useful += scores.size
         return out

@@ -114,7 +114,9 @@ def test_duplicate_tier_collapses_to_two_tier_plan():
 def test_plan_ntier_batch_matches_scalar():
     rng = np.random.default_rng(11)
     models = [random_ntier_model(rng, 3) for _ in range(32)]
-    tot, bounds, mig, strats = shp.plan_ntier_batch(models)
+    out = shp.solve_ntier_batch(models)
+    tot, bounds, mig = out["total"], out["bounds"], out["migrate"]
+    strats = shp.ntier_strategies(out, models)
     for i, m in enumerate(models):
         p = shp.plan_placement_ntier(m)
         assert strats[i] == p.strategy

@@ -34,15 +34,57 @@ def init(k: int) -> ReservoirState:
     )
 
 
+# The membership methods' costs on a TPU v5e (chip timings, vmapped rows
+# of 64 x 1,024 to 65,536 x 65,536): the fused compare tests about 1e12
+# needle-haystack pairs a second, the merged sort about 1.3e8 values a
+# second, so the compare is the faster while a row has no more than about
+# 8,192 pairs for each value the sort would take.
+COMPARE_PAIRS_PER_VALUE = 8192
+
+
+def member_method(n_needles: int, n_haystack: int) -> str:
+    """The method ``member`` uses for these static lengths: ``"compare"``
+    while ``n_needles * n_haystack`` is at most ``COMPARE_PAIRS_PER_VALUE``
+    times ``n_needles + n_haystack``, else ``"sort"``."""
+    if (n_needles * n_haystack
+            <= COMPARE_PAIRS_PER_VALUE * (n_needles + n_haystack)):
+        return "compare"
+    return "sort"
+
+
 @jax.named_scope("member")
 def member(needles: jax.Array, haystack: jax.Array) -> jax.Array:
-    """Boolean membership mask (``needles[i] in haystack``) via
-    sort + binary search — O((H+N)·log H) instead of ``jnp.isin``'s
-    O(N·H) broadcast compare, which dominates the exact path at huge K
-    (a K=65536 eviction scan is 4G compares per stream)."""
-    hs = jnp.sort(haystack)
-    pos = jnp.clip(jnp.searchsorted(hs, needles), 0, hs.shape[0] - 1)
-    return hs[pos] == needles
+    """Boolean membership mask (``needles[i] in haystack``), by one of two
+    methods that the static lengths pick (``member_method``):
+
+    - ``"compare"``: every needle against every haystack entry, reduced
+      with ``any``. XLA fuses the compare into the reduction, so the
+      (N, H) mask is never stored; no sort, no gather. At the fleet's
+      K = W = 1,024 it is one pass of ~1M pairs a stream.
+    - ``"sort"``: one sort of haystack and needles together, the haystack
+      first among equal values, then a needle is a member iff a haystack
+      entry precedes it in its run of equal values (cumulative counts, no
+      search). O((H+N)·log(H+N)), for rows where the O(N·H) compare would
+      dominate (a K = 65,536 scan of as many needles is 4G pairs a
+      stream). ``searchsorted``'s default binary search is a loop of
+      gathers, slow on a TPU.
+    """
+    n, h = needles.shape[0], haystack.shape[0]
+    if member_method(n, h) == "compare":
+        return (needles[:, None] == haystack[None, :]).any(axis=1)
+    idx = jnp.arange(n + h, dtype=jnp.int32)
+    tag = (idx >= h).astype(jnp.int32)  # 0 = haystack, 1 = needle
+    slot = jnp.where(idx >= h, idx - h, n)  # needle index; n = none
+    vals, tag, slot = jax.lax.sort(
+        (jnp.concatenate([haystack, needles]), tag, slot), num_keys=2)
+    hay = 1 - tag
+    hay_upto = jnp.cumsum(hay)
+    run_start = jnp.concatenate(
+        [jnp.ones((1,), bool), vals[1:] != vals[:-1]])
+    # haystack entries before the current run of equal values
+    hay_before = jax.lax.cummax(jnp.where(run_start, hay_upto - hay, 0))
+    hit = hay_upto > hay_before
+    return jnp.zeros((n,), bool).at[slot].set(hit, mode="drop")
 
 
 def _merge_sorted(scores: jax.Array, ids: jax.Array, k: int):
@@ -54,7 +96,8 @@ def _merge_sorted(scores: jax.Array, ids: jax.Array, k: int):
 
 
 def update(state: ReservoirState, batch_scores: jax.Array,
-           batch_ids: jax.Array) -> Tuple[ReservoirState, jax.Array]:
+           batch_ids: jax.Array, *, check_resident: bool = True
+           ) -> Tuple[ReservoirState, jax.Array]:
     """Merge a batch into the reservoir.
 
     Returns (new_state, wrote_mask) where ``wrote_mask[j]`` is True iff batch
@@ -62,13 +105,20 @@ def update(state: ReservoirState, batch_scores: jax.Array,
     Batch elements whose id is already resident are dropped — a re-observed
     document neither duplicates its slot nor triggers a storage write.
     Within-batch ids are assumed unique (they are stream indices).
+    ``check_resident=False`` skips that ``member`` search, for a caller
+    whose batch is already cleared of resident ids (pads as (-inf, -1)).
+
+    The entries it evicted are ``dropped(state, new_state)``, read off the
+    merge's order without another search.
     """
     k = state.scores.shape[0]
     batch_scores = batch_scores.astype(jnp.float32).reshape(-1)
     batch_ids = batch_ids.astype(jnp.int32).reshape(-1)
-    resident = member(batch_ids, state.ids)
-    cand_scores = jnp.where(resident, -jnp.inf, batch_scores)
-    cand_ids = jnp.where(resident, -1, batch_ids)
+    cand_scores, cand_ids = batch_scores, batch_ids
+    if check_resident:
+        resident = member(batch_ids, state.ids)
+        cand_scores = jnp.where(resident, -jnp.inf, batch_scores)
+        cand_ids = jnp.where(resident, -1, batch_ids)
     all_scores = jnp.concatenate([state.scores, cand_scores])
     all_ids = jnp.concatenate([state.ids, cand_ids])
     order = jnp.lexsort((all_ids, -all_scores))
@@ -86,8 +136,24 @@ def update(state: ReservoirState, batch_scores: jax.Array,
 
 def evicted(old: ReservoirState, new: ReservoirState) -> jax.Array:
     """Mask over ``old.ids`` of entries no longer present in ``new`` —
-    the documents whose storage can be freed (overwritten, paper §VI)."""
+    the documents whose storage can be freed (overwritten, paper §VI).
+    Searches ``new.ids`` for every old id (``member``), for any pair of
+    states; the engine step reads the same mask off the merge with
+    ``dropped``."""
     return (old.ids >= 0) & ~member(old.ids, new.ids)
+
+
+def dropped(old: ReservoirState, new: ReservoirState) -> jax.Array:
+    """``evicted`` for a ``new`` that ``update`` made from ``old``,
+    without a search: the merge keeps the K first entries in its
+    order (score descending, then id ascending), so an old entry survives
+    iff it ranks at or above ``new``'s K-th entry. Equal to ``evicted``
+    because valid ids are unique among the merged entries (resident
+    re-observations enter as (-inf, -1) pads) and scores are never NaN.
+    Works on stacked states too (the last axis is the reservoir)."""
+    bar_s, bar_i = new.scores[..., -1:], new.ids[..., -1:]
+    kept = (old.scores > bar_s) | ((old.scores == bar_s) & (old.ids <= bar_i))
+    return (old.ids >= 0) & ~kept
 
 
 def merge(a: ReservoirState, b: ReservoirState) -> ReservoirState:

@@ -94,10 +94,10 @@ def filtered_update(state: BatchedReservoirState, batch_scores: jax.Array,
         mask, _, _ = btk_ops.batched_topk_filter(batch_scores, bar,
                                                  block_n=block_n,
                                                  use_pallas=use_pallas)
-        # re-observed resident ids are dropped by topk.update anyway; mask
-        # them out *before* top_k so they cannot occupy a survivor slot
-        # that a fresh candidate (which plain ``update`` would admit)
-        # should get
+        # mask re-observed resident ids out *before* top_k so they cannot
+        # occupy a survivor slot that a fresh candidate (which plain
+        # ``update`` would admit) should get; the merge below then needs
+        # no resident search of its own
         resident = jax.vmap(topk.member)(batch_ids, state.ids)
         keep = (mask > 0) & ~resident
         surv = jnp.where(keep, batch_scores.astype(jnp.float32), -jnp.inf)
@@ -105,8 +105,9 @@ def filtered_update(state: BatchedReservoirState, batch_scores: jax.Array,
         top_ids = jnp.take_along_axis(batch_ids, top_idx, axis=1)
         top_ids = jnp.where(jnp.isfinite(top_scores), top_ids, PAD_ID)
     with jax.named_scope("merge"):
-        new, wrote_top = jax.vmap(topk.update)(_as_single(state),
-                                               top_scores, top_ids)
+        new, wrote_top = jax.vmap(
+            functools.partial(topk.update, check_resident=False))(
+                _as_single(state), top_scores, top_ids)
         # scatter the survivors' write mask back to batch positions
         wrote = jnp.zeros(batch_scores.shape, bool)
         rows = jnp.arange(batch_scores.shape[0])[:, None]
@@ -140,13 +141,23 @@ def placements(state: BatchedReservoirState, r) -> jax.Array:
     return jnp.where(state.ids >= 0, t, -1)
 
 
-@jax.named_scope("evicted")
 def evicted_ids(old: BatchedReservoirState,
                 new: BatchedReservoirState) -> jax.Array:
-    """(M, K) local doc ids evicted by the step (-1 = none) — the storage
-    the fleet can free (paper §VI)."""
+    """(M, K) local doc ids of ``old`` absent from ``new`` (-1 = none),
+    by id search (``topk.evicted``), for any pair of states."""
     ev = jax.vmap(topk.evicted)(_as_single(old), _as_single(new))
     return jnp.where(ev, old.ids, PAD_ID)
+
+
+@jax.named_scope("evicted")
+def dropped_ids(old: BatchedReservoirState,
+                new: BatchedReservoirState) -> jax.Array:
+    """(M, K) local doc ids evicted by the step (-1 = none) — the storage
+    the fleet can free (paper §VI). ``new`` is ``update``'s or
+    ``filtered_update``'s result from ``old``, so the evictions are read
+    off the merge's order (``topk.dropped``); equal to ``evicted_ids``."""
+    return jnp.where(topk.dropped(_as_single(old), _as_single(new)),
+                     old.ids, PAD_ID)
 
 
 @functools.lru_cache(maxsize=64)
@@ -256,7 +267,7 @@ def _make_step(use_kernel_filter: bool, block_n: int, drift_cfg=None,
                                                  use_pallas=use_kernel_filter)
                 else:
                     new, wrote = update(st, s, i)
-                ev = evicted_ids(st, new)
+                ev = dropped_ids(st, new)
                 bar = st.scores[:, -1]
                 slack = 0.0
                 if with_costs:
@@ -583,8 +594,9 @@ class StreamEngine:
             with_costs=self._cost_states is not None)
         self._step = self._step_factory(False)
         self._donating_step = None  # built lazily by ingest_chunks
-        # every dispatch is counted per (bucket widths, donate) signature,
-        # so a recompile of the step is named in the jit snapshot
+        # every dispatch is counted per (bucket widths, donate, exact
+        # buckets' membership methods) signature, so a recompile of the
+        # step is named in the jit snapshot
         self._step_probe = jits.probe("streams.engine.step")
         # resilience (repro.resilience): the ingest cursor is the chunk
         # sequence number — checkpoint step, and the idempotent
@@ -682,7 +694,12 @@ class StreamEngine:
             step = self._donating_step
         else:
             step = self._step
-        key = (tuple(int(s.shape[1]) for s, _ in batches), donate)
+        widths = tuple(int(s.shape[1]) for s, _ in batches)
+        # the membership search each exact bucket was compiled with
+        members = tuple(topk.member_method(w, b.k)
+                        for w, b in zip(widths, self.buckets)
+                        if b.engine == "exact")
+        key = (widths, donate, members)
         with self._span("ingest.dispatch"):
             new_states, wrotes, evs, new_dstates, mstate, new_cstates = \
                 self._step_probe.track(step, tuple(self._states), batches,

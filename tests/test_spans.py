@@ -164,7 +164,7 @@ def test_step_probe_hits_repeated_shape_and_misses_new_width():
     # a reservoir width no other test uses, so its jitted step is fresh
     eng, _ = _engine(m=3, k=5)
     probe = jits.probe("streams.engine.step")
-    key = str(((16,), False))
+    key = str(((16,), False, ("compare",)))
     eng.ingest_dense(_dense(eng, 0))
     first = dict(probe.by_key[key])
     assert first["misses"] >= 1
@@ -172,10 +172,38 @@ def test_step_probe_hits_repeated_shape_and_misses_new_width():
     again = probe.by_key[key]
     assert again["calls"] == first["calls"] + 1
     assert again["misses"] == first["misses"]  # a hit
-    wide = str(((32,), False))
+    wide = str(((32,), False, ("compare",)))
     misses = probe.by_key.get(wide, {"misses": 0})["misses"]
     eng.ingest_dense(_dense(eng, 2, w=32))
     assert probe.by_key[wide]["misses"] == misses + 1
+
+
+@pytest.mark.parametrize("fleet,w,members", [
+    ("exact", 16, ("compare",)),
+    ("exact_wide", 16400, ("sort",)),  # K = W = 16,400
+    ("mixed", 16, ("compare",)),
+    ("logmem", 16, ()),
+])
+def test_step_probe_reports_membership_method(fleet, w, members):
+    """The step probe's key names the membership search each exact
+    bucket was compiled with; logmem buckets search nothing."""
+    specs = {
+        "exact": [StreamSpec(stream_id=i, k=8, r=32.0) for i in range(2)],
+        "exact_wide": [StreamSpec(stream_id=0, k=16400, r=65600.0)],
+        "mixed": [StreamSpec(stream_id=0, k=8, r=32.0),
+                  StreamSpec(stream_id=1, k=64, r=256.0, engine="logmem")],
+        "logmem": [StreamSpec(stream_id=i, k=64, r=256.0, engine="logmem")
+                   for i in range(2)],
+    }[fleet]
+    obs = Observability(ObsConfig())
+    eng = StreamEngine(specs, obs=obs)
+    before = jits.probe("streams.engine.step").snapshot()["by_key"]
+    eng.ingest_dense(_dense(eng, 0, w=w))
+    after = obs.snapshot()["jit"]["streams.engine.step"]["by_key"]
+    grown = [key for key, v in after.items()
+             if v["calls"] > before.get(key, {"calls": 0})["calls"]]
+    widths = (w,) * len(eng.buckets)
+    assert grown == [str((widths, False, members))]
 
 
 def test_lowered_step_carries_named_scopes():

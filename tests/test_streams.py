@@ -175,6 +175,50 @@ def test_filtered_update_equals_plain_update(use_pallas):
                                       np.sort(np.asarray(st_filt.ids), 1))
 
 
+def _eviction_batch(case, rng, state, step, m, w):
+    """One batch per stream for ``case``: increasing fresh ids, plus
+    residents re-sent at a top score, pads, or tied scores."""
+    scores = (rng.integers(0, 3, (m, w)) if case == "tied"
+              else rng.standard_normal((m, w))).astype(np.float32)
+    ids = np.tile(np.arange(step * w, (step + 1) * w, dtype=np.int32),
+                  (m, 1))
+    if case == "reobserved" and step:
+        res = np.asarray(state.ids)
+        ids[:, 0], scores[:, 0] = res[:, 0], 100.0
+    if case == "padded":
+        ids[:, w // 2:], scores[:, w // 2:] = -1, -np.inf
+        ids[0], scores[0] = -1, -np.inf
+    return jnp.asarray(scores), jnp.asarray(ids)
+
+
+@pytest.mark.parametrize("path", ["update", "filtered_jnp",
+                                  "filtered_pallas"])
+@pytest.mark.parametrize("case", ["unfull", "full", "reobserved", "padded",
+                                  "tied"])
+def test_step_evictions_by_rank_equal_id_search(path, case):
+    """The step's evictions (``dropped_ids``, read off the merge's order)
+    equal the id search ``evicted_ids`` after each update path."""
+    rng = np.random.default_rng(11)
+    m, k = 4, 8
+    w = 2 if case == "unfull" else 16  # unfull: 3 chunks never fill K
+    fns = {"update": engine.update,
+           "filtered_jnp": lambda *a: engine.filtered_update(
+               *a, block_n=128, use_pallas=False),
+           "filtered_pallas": lambda *a: engine.filtered_update(
+               *a, block_n=128, use_pallas=True)}
+    st = engine.init(m, k)
+    evicted = 0
+    for step in range(3):
+        sc, ids = _eviction_batch(case, rng, st, step, m, w)
+        new, _ = fns[path](st, sc, ids)
+        want = np.asarray(engine.evicted_ids(st, new))
+        np.testing.assert_array_equal(np.asarray(engine.dropped_ids(st, new)),
+                                      want)
+        evicted += int((want >= 0).sum())
+        st = new
+    assert (evicted == 0) == (case == "unfull")
+
+
 def test_engine_bit_matches_simulator_replays():
     """The acceptance property at test scale: heterogeneous fleet through
     shuffled mixed batches == M independent core.simulator replays."""

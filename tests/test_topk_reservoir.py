@@ -116,3 +116,90 @@ def test_tier_of_threshold():
     ids = jnp.array([0, 5, 10, 99], jnp.int32)
     t = topk.tier_of(ids, r=10)
     assert list(np.asarray(t)) == [0, 0, 1, 1]
+
+
+EVICTION_CASES = ("unfull", "full", "reobserved", "padded", "tied")
+
+
+def _eviction_case(case, m=6, k=8, w=12, seed=7):
+    """A stacked fleet of ``m`` reservoirs after one warm-up batch, and
+    the next batch, shaped to exercise ``case``."""
+    rng = np.random.default_rng(seed)
+    upd = jax.vmap(topk.update)
+    fill = 3 if case == "unfull" else 2 * k
+    if case == "unfull":
+        w = k - fill - 1  # the batch fits: no row fills up
+    draw = ((lambda n: rng.integers(0, 3, (m, n)).astype(np.float32))
+            if case == "tied" else
+            (lambda n: rng.standard_normal((m, n)).astype(np.float32)))
+    state = jax.vmap(lambda _: topk.init(k))(jnp.arange(m))
+    ids0 = np.tile(np.arange(fill, dtype=np.int32), (m, 1))
+    state, _ = upd(state, jnp.asarray(draw(fill)), jnp.asarray(ids0))
+    scores = draw(w)
+    ids = np.tile(np.arange(fill, fill + w, dtype=np.int32), (m, 1))
+    if case == "reobserved":
+        # every row re-sends two of its residents, one at a top score
+        res = np.asarray(state.ids)
+        ids[:, :2] = res[:, :2]
+        scores[:, 0] = 100.0
+    if case == "padded":
+        ids[:, w // 2:] = -1
+        scores[:, w // 2:] = -np.inf
+        ids[0], scores[0] = -1, -np.inf  # an all-pad row
+    return state, jnp.asarray(scores), jnp.asarray(ids)
+
+
+@pytest.mark.parametrize("case", EVICTION_CASES)
+def test_dropped_equals_evicted(case):
+    old, scores, ids = _eviction_case(case)
+    new, _ = jax.vmap(topk.update)(old, scores, ids)
+    want = np.asarray(jax.vmap(topk.evicted)(old, new))
+    np.testing.assert_array_equal(np.asarray(topk.dropped(old, new)), want)
+    np.testing.assert_array_equal(
+        np.asarray(jax.vmap(topk.dropped)(old, new)), want)
+    if case == "unfull":
+        assert not want.any()
+    if case in ("full", "tied", "reobserved"):
+        assert want.any()
+
+
+@pytest.mark.parametrize("case", EVICTION_CASES)
+def test_update_without_resident_check_on_cleared_batch(case):
+    """``check_resident=False`` on a batch already cleared of residents
+    is bit-equal to the checked update of the raw batch."""
+    old, scores, ids = _eviction_case(case)
+    new, wrote = jax.vmap(topk.update)(old, scores, ids)
+    resident = jax.vmap(topk.member)(ids, old.ids)
+    cleared = (jnp.where(resident, -jnp.inf, scores),
+               jnp.where(resident, -1, ids))
+    new2, wrote2 = jax.vmap(
+        lambda s, sc, i: topk.update(s, sc, i, check_resident=False))(
+            old, *cleared)
+    for a, b in zip(new, new2):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(wrote), np.asarray(wrote2))
+
+
+@pytest.mark.parametrize("n,h,natural", [
+    (16, 64, "compare"), (1024, 1024, "compare"), (64, 131072, "compare"),
+    (16384, 16384, "compare"), (16400, 16400, "sort")])
+def test_member_methods_agree_with_isin(n, h, natural, monkeypatch):
+    """Both methods equal ``np.isin`` on both sides of the threshold,
+    with -1 pads and repeats among needles and haystack."""
+    assert topk.member_method(n, h) == natural
+    rng = np.random.default_rng(n + h)
+    hay = rng.choice(4 * h, h, replace=False).astype(np.int32)
+    hay[-(h // 8):] = -1
+    hay[:3] = hay[3]
+    needles = rng.integers(-1, 4 * h, n).astype(np.int32)
+    needles[: n // 4] = rng.choice(hay, n // 4)
+    needles[-3:] = -1
+    want = np.isin(needles, hay)
+    per_value = n * h // (n + h)
+    for method, cap in (("compare", per_value + 1), ("sort", per_value - 1)):
+        monkeypatch.setattr(topk, "COMPARE_PAIRS_PER_VALUE", cap)
+        assert topk.member_method(n, h) == method
+        # a fresh jit per method: the method is read while tracing
+        got = jax.jit(lambda a, b: topk.member(a, b))(jnp.asarray(needles),
+                                                      jnp.asarray(hay))
+        np.testing.assert_array_equal(np.asarray(got), want, err_msg=method)

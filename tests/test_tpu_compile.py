@@ -1,7 +1,7 @@
-"""Compile the main path's Pallas kernels, the planner program and the
-fleet step for a described, unattached TPU v5e chip at the smoke test's
-widths (M = 65,536 streams, W = 1,024 docs per chunk, K = 1,024; the
-logmem scan also at W = 8,192). Nothing runs: the TPU compiler refuses
+"""Compile the main path's Pallas kernels, the planner program, the
+reservoirs' membership searches and the fleet step for a described,
+unattached TPU v5e chip at the smoke test's widths (M = 65,536 streams,
+W = 1,024 docs per chunk, K = 1,024; the logmem scan also at W = 8,192). Nothing runs: the TPU compiler refuses
 what the chip would refuse — block tiling, VMEM, device memory.
 
 The topology is described inside a module fixture (only one process may
@@ -134,6 +134,21 @@ def test_fleet_step_compiles(one_chip, compiled_kernels, kernel):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert used < 16e9, used
+
+
+@pytest.mark.parametrize("rows,n,h,method", [(M, W, K, "compare"),
+                                              (LM, LK // 2, LK // 2, "sort")])
+def test_member_compiles_without_loop(one_chip, rows, n, h, method):
+    """Both membership searches over a fleet's rows: no ``while`` loop of
+    gathers, and scratch memory grows with the values, not with the
+    (N, H) pairs (the compare's mask is never stored)."""
+    from repro.core import topk
+    assert topk.member_method(n, h) == method
+    c = jax.jit(jax.vmap(topk.member)).lower(
+        _spec(one_chip, (rows, n), jnp.int32),
+        _spec(one_chip, (rows, h), jnp.int32)).compile()
+    assert " while(" not in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < rows * (n + h) * 32
 
 
 def test_sharded_plan_compiles(four_chips, compiled_kernels):

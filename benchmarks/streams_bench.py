@@ -85,7 +85,8 @@ def _engine_step_pair(emit, m, rng):
         for _, eng, batches, mstate, cstates, best in variants:
             best[0] = min(best[0],
                           _time(eng._step, tuple(eng._states), batches,
-                                (), mstate, cstates, reps=25))
+                                (), mstate, cstates,
+                                tuple(eng._meter_states), reps=25))
     for suffix, _, _, _, _, best in variants:
         us = best[0]
         emit(f"streams.engine_step{suffix}_m{m}_k{K}_b{BATCH}", us,
@@ -123,7 +124,8 @@ def _backend_rows(emit, rng):
             for _, eng, best in variants:
                 best[0] = min(best[0],
                               _time(eng._step, tuple(eng._states), batches,
-                                    (), (), (), reps=reps))
+                                    (), (), (), tuple(eng._meter_states),
+                                    reps=reps))
         for backend, eng, best in variants:
             us = best[0]
             bps = _state_bytes_per_stream(eng._states)
@@ -236,11 +238,11 @@ def _sharded_step_rows(emit, rng):
         sh = fleet.row_sharding(mesh)
         variants = [
             ("ref1", step1, ((st,), ((jnp.asarray(sc),
-                                      jnp.asarray(ids)),), (), (), ())),
+                                      jnp.asarray(ids)),), (), (), (), ())),
             (f"sharded_d{shards}", stepd,
              (((fleet.shard_rows(mesh, st)),),
               ((jax.device_put(sc, sh), jax.device_put(ids, sh)),),
-              (), (), ())),
+              (), (), (), ())),
         ]
         best = {name: float("inf") for name, _, _ in variants}
         for _ in range(rounds):  # interleaved: same machine weather

@@ -18,6 +18,7 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 class ReservoirState(NamedTuple):
@@ -175,3 +176,30 @@ def threshold(state: ReservoirState) -> jax.Array:
 def tier_of(ids: jax.Array, r: float | jax.Array) -> jax.Array:
     """Algorithm C placement: tier 0 (A) for stream index < r, else 1 (B)."""
     return (ids >= jnp.asarray(r)).astype(jnp.int32)
+
+
+INT32_MAX = np.iinfo(np.int32).max
+
+
+def quantize_boundaries(bounds) -> np.ndarray:
+    """(..., B) float boundary vectors -> exact int32 thresholds. Doc ids
+    are integer positions, so ``id >= b`` is exactly ``id >= ceil(b)``;
+    +inf boundaries (the padding of shallower streams) map to INT32_MAX,
+    which no position reaches."""
+    b = np.asarray(bounds, np.float64)
+    return np.where(np.isfinite(b), np.clip(np.ceil(b), 0, INT32_MAX),
+                    INT32_MAX).astype(np.int32)
+
+
+def tiers(ids: jax.Array, bounds: jax.Array) -> jax.Array:
+    """(M, N) static tier of each position: the number of the row's
+    ``quantize_boundaries`` thresholds (M, B) at or below it. An int32
+    compare, exact at every position (a float32 one is not past 2^24)."""
+    return (ids[:, :, None] >= bounds[:, None, :]).sum(-1, dtype=jnp.int32)
+
+
+def tier_counts(tier: jax.Array, mask: jax.Array, n_tiers: int) -> jax.Array:
+    """(M, T) int32 count of the masked entries of each row per tier
+    (T static: T masked row sums, fused)."""
+    return jnp.stack([jnp.sum(mask & (tier == t), axis=1, dtype=jnp.int32)
+                      for t in range(n_tiers)], axis=1)

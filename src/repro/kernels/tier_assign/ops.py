@@ -15,23 +15,13 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core import jaxcompat
+from repro.core.topk import quantize_boundaries
 
 from ..common import pad_rows, row_tiling
 from . import ref
 from .tier_assign import tier_assign_pallas
-
-_INT_MAX = np.iinfo(np.int32).max
-
-
-def quantize_boundaries(bounds) -> np.ndarray:
-    """(M, B) float boundary vectors -> exact int32 thresholds."""
-    b = np.asarray(bounds, np.float64)
-    return np.where(np.isfinite(b),
-                    np.clip(np.ceil(b), 0, _INT_MAX), _INT_MAX
-                    ).astype(np.int32)
 
 
 @partial(jax.jit, static_argnames=("n_tiers", "block_k", "use_pallas"))

@@ -45,10 +45,12 @@ Three pieces:
 
 Device-ledger scope: tiers are attributed by each doc's *static*
 position tier against the stream's current boundary vector (the leaf is
-updated by the host after a re-plan — no recompiles). Migration-cascade
-streams lift residents above the static tier; their hop accounting
-stays in the host ``FleetMeter`` (``mig_reads``/``mig_writes``), and the
-reconciliation guarantees below are stated for non-cascade streams.
+updated by the host after a re-plan — no recompiles), with the same
+int32 attribution as the meter's device fold (``topk.tiers``).
+Migration-cascade streams lift residents above the static tier; their
+hop accounting is the ``FleetMeter``'s (``mig_reads``/``mig_writes``,
+counted by the meter fold in the step), and the reconciliation
+guarantees below are stated for non-cascade streams.
 """
 from __future__ import annotations
 
@@ -59,6 +61,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import topk
+
 from .residuals import chunk_law_np
 
 
@@ -68,14 +72,15 @@ from .residuals import chunk_law_np
 
 class CostState(NamedTuple):
     """Per-bucket device cost ledger (rows = the bucket's streams, padded
-    to the shard multiple; pad rows carry +inf bounds and never count).
+    to the shard multiple; pad rows carry INT32_MAX bounds and never
+    count).
 
-    ``bounds`` holds the *ceiled* boundary vector in f32: doc ids are
-    integers, so ``id >= ceil(b)`` ⟺ ``id >= b``, and ceiled edges are
-    exactly representable in f32 (up to 2^24) — the device tier
+    ``bounds`` holds the boundary vector as ``topk.quantize_boundaries``
+    gives it: doc ids are integers, so ``id >= ceil(b)`` ⟺ ``id >= b``,
+    and the int32 compare is exact at every position — the device tier
     attribution is bit-equal to the host meter's f64 comparison."""
 
-    bounds: jax.Array  # (Mb, B) f32 — ceiled boundaries, +inf padded
+    bounds: jax.Array  # (Mb, B) i32 — ceiled boundaries, INT32_MAX padded
     writes: jax.Array  # (Mb, T) i32 — admits priced cw at the write tier
     deletes: jax.Array  # (Mb, T) i32 — evictions per (current static) tier
     resident_steps: jax.Array  # (Mb, T) i32 — Σ occupancy × chunk docs
@@ -85,39 +90,15 @@ def init_bucket(pad_m: int, boundaries: np.ndarray,
                 n_tiers: int) -> CostState:
     """Fresh ledger for one bucket: ``boundaries`` is the meter's
     (m_true, B) f64 block for the bucket's rows; rows past it are
-    shard padding (+inf bounds — inert)."""
+    shard padding (INT32_MAX bounds — inert)."""
     b = np.asarray(boundaries, np.float64)
-    bounds = np.full((pad_m, b.shape[1]), np.inf, np.float32)
-    bounds[: b.shape[0]] = np.ceil(b).astype(np.float32)
+    bounds = np.full((pad_m, b.shape[1]), topk.INT32_MAX, np.int32)
+    bounds[: b.shape[0]] = topk.quantize_boundaries(b)
     return CostState(
         bounds=jnp.asarray(bounds),
         writes=jnp.zeros((pad_m, n_tiers), jnp.int32),
         deletes=jnp.zeros((pad_m, n_tiers), jnp.int32),
         resident_steps=jnp.zeros((pad_m, n_tiers), jnp.int32))
-
-
-def set_bucket_bounds(cs: CostState, row: int, bounds_row) -> CostState:
-    """Host-side boundary swap after a re-plan: one row of the bounds
-    leaf is replaced (ceiled, +inf padded) — a device scatter, no
-    recompile (the leaf's shape is unchanged)."""
-    b = np.full(cs.bounds.shape[1], np.inf, np.float32)
-    vec = np.asarray(bounds_row, np.float64).reshape(-1)
-    b[: vec.shape[0]] = np.ceil(vec).astype(np.float32)
-    return cs._replace(bounds=cs.bounds.at[row].set(jnp.asarray(b)))
-
-
-def _tier_of(ids, bounds):
-    """(Mb, W) static tier = number of boundaries <= id (ids are
-    integer positions; bounds are ceiled, see ``CostState``)."""
-    return (ids[:, :, None].astype(jnp.float32)
-            >= bounds[:, None, :]).sum(-1).astype(jnp.int32)
-
-
-def _per_tier(tiers, mask, n_tiers: int):
-    """(Mb, T) i32 masked per-tier counts (static small-T loop — T is a
-    trace-time constant, so this unrolls into T masked reductions)."""
-    return jnp.stack([jnp.sum(mask & (tiers == t), axis=1, dtype=jnp.int32)
-                      for t in range(n_tiers)], axis=1)
 
 
 @jax.named_scope("obs")
@@ -130,9 +111,11 @@ def accumulate_exact(cs: CostState, batch_ids, wrote, evicted_ids,
     simulator's per-doc rental at chunk width 1."""
     t = cs.writes.shape[1]
     live = batch_ids >= 0
-    dw = _per_tier(_tier_of(batch_ids, cs.bounds), wrote & live, t)
-    dd = _per_tier(_tier_of(evicted_ids, cs.bounds), evicted_ids >= 0, t)
-    occ = _per_tier(_tier_of(state_ids, cs.bounds), state_ids >= 0, t)
+    dw = topk.tier_counts(topk.tiers(batch_ids, cs.bounds), wrote & live, t)
+    dd = topk.tier_counts(topk.tiers(evicted_ids, cs.bounds),
+                          evicted_ids >= 0, t)
+    occ = topk.tier_counts(topk.tiers(state_ids, cs.bounds), state_ids >= 0,
+                           t)
     docs = live.sum(axis=1, dtype=jnp.int32)
     return cs._replace(writes=cs.writes + dw, deletes=cs.deletes + dd,
                        resident_steps=cs.resident_steps
@@ -146,7 +129,7 @@ def accumulate_logmem(cs: CostState, batch_ids, wrote) -> CostState:
     accrues the post-step cumulative write counts."""
     t = cs.writes.shape[1]
     live = batch_ids >= 0
-    dw = _per_tier(_tier_of(batch_ids, cs.bounds), wrote & live, t)
+    dw = topk.tier_counts(topk.tiers(batch_ids, cs.bounds), wrote & live, t)
     writes = cs.writes + dw
     docs = live.sum(axis=1, dtype=jnp.int32)
     return cs._replace(writes=writes,

@@ -175,6 +175,9 @@ def fleet_restore(engine, tree: Dict, meta: Dict) -> None:
             _restore_bucket(engine, bi, device["costs"][bi], fresh[bi])
             for bi in range(len(engine.buckets))]
     engine.meter.load_state(tree["host"]["meter"])
+    # the meter fold's device rows (and the cost ledgers' boundaries) are
+    # copies of the host meter's: reload them from the restored ledgers
+    engine._load_device_meter()
     if engine._residuals is not None:
         engine._residuals.load_state(tree["host"]["residuals"])
     if engine._cost_monitor is not None:

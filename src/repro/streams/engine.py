@@ -203,6 +203,13 @@ def _make_step(use_kernel_filter: bool, block_n: int, drift_cfg=None,
     fused reductions over values the step already materializes, and
     ``cstates = ()`` when off leaves the traced computation unchanged.
 
+    With ``meters`` (one ``metering.MeterState`` per bucket) the step
+    also meters each bucket on the device (``metering.fold``): it returns
+    the advanced meter states and each bucket's per-(stream, tier)
+    ``MeterDelta`` for ``FleetMeter.record_update``, so no per-document
+    array leaves the device. ``meters = ()`` skips the fold (both
+    outputs are then empty tuples).
+
     With ``mesh`` (a ``parallel.fleet`` mesh) the whole step is
     ``shard_map``-ped over the fleet axis: every leading-M leaf —
     reservoir state, batch, drift state — splits across devices and each
@@ -227,13 +234,13 @@ def _make_step(use_kernel_filter: bool, block_n: int, drift_cfg=None,
     if update_path not in ("auto", "fused"):
         raise ValueError(f"unknown update_path {update_path!r}")
 
-    def step(states, batches, dstates, mstate, cstates):
+    def step(states, batches, dstates, mstate, cstates, meters):
         if with_metrics and mesh is not None:
             # inside shard_map: squeeze this shard's (1, 8) counter
             # block to the flat layout the accumulate laws expect
             mstate = metrics_mod.shard_local(mstate)
-        new_states, wrotes, evs, new_dstates = [], [], [], []
-        new_cstates = []
+        new_states, new_dstates, new_cstates = [], [], []
+        new_meters, deltas = [], []
         for bi, (st, (s, i)) in enumerate(zip(states, batches)):
             # quarantine non-finite scores before any compare sees them:
             # NaN fails every comparison (it would never be admitted and
@@ -257,6 +264,7 @@ def _make_step(use_kernel_filter: bool, block_n: int, drift_cfg=None,
                 ev = jnp.full((s.shape[0], 0), PAD_ID, jnp.int32)
                 bar = st.tau
                 slack = logmem.law_slack(bucket_ks[bi])
+                stored = ()
                 if with_costs:
                     new_cstates.append(costs_mod.accumulate_logmem(
                         cstates[bi], i, wrote))
@@ -270,12 +278,15 @@ def _make_step(use_kernel_filter: bool, block_n: int, drift_cfg=None,
                 ev = dropped_ids(st, new)
                 bar = st.scores[:, -1]
                 slack = 0.0
+                stored = (ev, new.ids)
                 if with_costs:
                     new_cstates.append(costs_mod.accumulate_exact(
                         cstates[bi], i, wrote, ev, new.ids))
             new_states.append(new)
-            wrotes.append(wrote)
-            evs.append(ev)
+            if meters:
+                ms, delta = metering.fold(meters[bi], i, wrote, *stored)
+                new_meters.append(ms)
+                deltas.append(delta)
             if drift_cfg is not None:
                 new_dstates.append(drift_mod.update(
                     dstates[bi], wrote.sum(axis=1), new.seen,
@@ -300,14 +311,14 @@ def _make_step(use_kernel_filter: bool, block_n: int, drift_cfg=None,
             mstate = metrics_mod.bump_chunk(mstate)
         if with_metrics and mesh is not None:
             mstate = metrics_mod.shard_pack(mstate)
-        return tuple(new_states), tuple(wrotes), tuple(evs), \
-            tuple(new_dstates), mstate, tuple(new_cstates)
+        return tuple(new_states), tuple(new_dstates), mstate, \
+            tuple(new_cstates), tuple(new_meters), tuple(deltas)
 
     if mesh is not None:
         from repro.core import jaxcompat
         from repro.parallel import fleet
         spec = fleet.row_spec()
-        step = jaxcompat.shard_map(step, mesh=mesh, in_specs=(spec,) * 5,
+        step = jaxcompat.shard_map(step, mesh=mesh, in_specs=(spec,) * 6,
                                    out_specs=(spec,) * 6)
     return jax.jit(step, donate_argnums=(0, 2, 3, 4) if donate else ())
 
@@ -583,6 +594,10 @@ class StreamEngine:
                 law_slack=slack_rows, logmem=self.meter.logmem,
                 budget_factor=obs.config.budget_factor,
                 burn_windows=obs.config.burn_windows)
+        # the meter rows the step's meter fold reads, on the device
+        self._meter_states: List[metering.MeterState] = [None] * len(
+            self.buckets)
+        self._load_device_meter()
         self._step_factory = lambda donate: _make_step(
             use_kernel_filter, block_n,
             drift_cfg=None if replan is None else replan.drift,
@@ -678,10 +693,35 @@ class StreamEngine:
                 out.append((jax.device_put(s, sh), jax.device_put(i, sh)))
             return tuple(out)
 
-    def _dispatch(self, batches, donate: bool):
+    def _place(self, tree):
+        """Host arrays of per-row leaves -> device (row-sharded under a
+        mesh)."""
+        if self.mesh is not None:
+            from repro.parallel import fleet
+            return fleet.shard_rows(self.mesh, tree)
+        return jax.tree_util.tree_map(jnp.asarray, tree)
+
+    def _load_device_meter(self, buckets: Optional[Sequence[int]] = None
+                           ) -> None:
+        """(Re)load the device copies of the host meter's rows — the
+        state the step's meter fold reads, and the cost ledger's
+        boundaries — after the host changed them (a re-plan, an
+        evacuation, a restore). Shapes are unchanged, so nothing
+        recompiles."""
+        for bi in (range(len(self.buckets)) if buckets is None
+                   else buckets):
+            host = self.meter.device_state(self._global_rows[bi],
+                                           self._pad_m[bi])
+            self._meter_states[bi] = self._place(host)
+            if self._cost_states is not None:
+                # a copy of its own: the step donates the cost ledger
+                self._cost_states[bi] = self._cost_states[bi]._replace(
+                    bounds=self._place(host.bounds))
+
+    def _dispatch(self, batches, donate: bool, meter: bool = True):
         """Run one (already staged) fleet step and swap in the new
-        device states. Returns (wrotes, evs, new_states) for the host
-        meter; all three are still in-flight device arrays."""
+        device states (the meter's only when ``meter``). Returns
+        (deltas, new_states) for the host meter, still in flight."""
         dstates = (tuple(self._drift_states)
                    if self._drift_states is not None else ())
         mstate = (self._metrics_state
@@ -701,9 +741,10 @@ class StreamEngine:
                         if b.engine == "exact")
         key = (widths, donate, members)
         with self._span("ingest.dispatch"):
-            new_states, wrotes, evs, new_dstates, mstate, new_cstates = \
-                self._step_probe.track(step, tuple(self._states), batches,
-                                       dstates, mstate, cstates, key=key)
+            (new_states, new_dstates, mstate, new_cstates, new_meters,
+             deltas) = self._step_probe.track(
+                step, tuple(self._states), batches, dstates, mstate,
+                cstates, tuple(self._meter_states), key=key)
         self._states = list(new_states)
         if self._metrics_state is not None:
             self._metrics_state = mstate
@@ -711,38 +752,29 @@ class StreamEngine:
             self._drift_states = list(new_dstates)
         if self._cost_states is not None:
             self._cost_states = list(new_cstates)
-        return wrotes, evs, new_states
+        if meter:
+            self._meter_states = list(new_meters)
+        return deltas, new_states
 
-    def _consume(self, dense, wrotes, evs, new_states,
-                 meter: bool = True) -> None:
-        """Host side of one step: wait for its outputs, copy what the
-        meter needs to the host (slicing any sharded padding back off),
-        meter the transactions, drain residuals, maybe re-plan."""
+    def _consume(self, deltas, new_states, meter: bool = True) -> None:
+        """Host side of one step: wait for its outputs, copy the meter's
+        per-(stream, tier) counts to the host (slicing any sharded
+        padding back off), add them to the ledgers, drain residuals,
+        maybe re-plan."""
         if not meter:
             return
-        # mirror the device quarantine: docs whose score is non-finite
-        # were demoted to pad slots in the step, so the host meter must
-        # not count them as observed either (host-only work, done while
-        # the step may still run on the device)
-        dense_ids = [i if np.isfinite(s).all()
-                     else np.where(np.isfinite(s), i, router.PAD_ID)
-                     for s, i in dense]
         with self._span("ingest.wait"):
-            jax.block_until_ready((wrotes, evs, new_states))
+            jax.block_until_ready((deltas, new_states))
         with self._span("ingest.fetch") as sp:
-            # logmem buckets have no resident ids: no cascade check, and
-            # their (mb, 0) eviction set scatters nothing
-            fetched = [(np.asarray(wrotes[bi])[:b.m],
-                        np.asarray(evs[bi])[:b.m],
-                        None if b.engine == "logmem"
-                        else np.asarray(new_states[bi].ids)[:b.m])
-                       for bi, b in enumerate(self.buckets)]
-            sp.attrs["bytes"] = sum(a.nbytes for f in fetched for a in f
-                                    if a is not None)
+            fetched = [metering.MeterDelta(*(a[:b.m] for a in d))
+                       for d, b in zip(jax.device_get(deltas),
+                                       self.buckets)]
+            sp.attrs["bytes"] = sum(a.nbytes for d in fetched for a in d)
         with self._span("ingest.meter"):
-            for bi, (wrote, ev, st_ids) in enumerate(fetched):
-                self.meter.record_update(self._global_rows[bi],
-                                         dense_ids[bi], wrote, ev, st_ids)
+            for rows, delta in zip(self._global_rows, fetched):
+                # a bucket's rows are contiguous: index them by a slice
+                self.meter.record_update(
+                    slice(int(rows[0]), int(rows[-1]) + 1), delta)
         with self._span("ingest.monitors"):
             residual_rows, cost_rows = self._update_monitors()
         if self._drift_states is not None:
@@ -809,8 +841,8 @@ class StreamEngine:
     def _run_chunk(self, dense, *, meter: bool = True,
                    donate: bool = False) -> None:
         batches = self._stage_batches(dense)
-        wrotes, evs, new_states = self._dispatch(batches, donate)
-        self._consume(dense, wrotes, evs, new_states, meter=meter)
+        deltas, new_states = self._dispatch(batches, donate, meter)
+        self._consume(deltas, new_states, meter=meter)
         self._chunk_boundary()
 
     def _chunk_boundary(self) -> None:
@@ -868,14 +900,13 @@ class StreamEngine:
         staged = self._stage_batches(nxt) if nxt is not None else None
         count = 0
         while staged is not None:
-            dense = nxt
             # dispatch is async: the step runs while we stage chunk t+1
-            wrotes, evs, new_states = self._dispatch(staged, donate=True)
+            deltas, new_states = self._dispatch(staged, True, meter)
             nxt = next(it, None)
             staged = (self._stage_batches(nxt, self.chunks_ingested + 1)
                       if nxt is not None else None)
             # host consumption blocks on chunk t's outputs last
-            self._consume(dense, wrotes, evs, new_states, meter=meter)
+            self._consume(deltas, new_states, meter=meter)
             # chunk-boundary checkpoint: the device→host copies read
             # finished buffers, the npy write runs on the manager's
             # worker thread while chunk t+1 (already staged) computes
@@ -945,21 +976,8 @@ class StreamEngine:
             if not dec.feasible[j]:
                 self._negotiate_admission(int(row), int(dec.n_seen[j]))
             if dec.applied[j]:
-                bi, jb = bucket_of[j], row_in_bucket[j]
-                ids_arg = (None if self.buckets[bi].engine == "logmem"
-                           else np.asarray(self._states[bi].ids[jb]))
-                moved = self.meter.apply_boundaries(
-                    int(row), dec.new_bounds[j], ids_arg)
-                touched_buckets.add(bi)
-                if self._cost_states is not None:
-                    # swap the device ledger's boundary row (a scatter —
-                    # no recompile) and the monitor's planned trajectory
-                    from repro.obs import costs as costs_mod
-                    self._cost_states[bi] = costs_mod.set_bucket_bounds(
-                        self._cost_states[bi], jb,
-                        self.meter.boundaries[int(row)])
-                    self._cost_monitor.set_bounds(
-                        int(row), self.meter.boundaries[int(row)])
+                moved = self._apply_row_bounds(int(row), dec.new_bounds[j])
+                touched_buckets.add(bucket_of[j])
             self.replan_events.append(ReplanEvent(
                 stream_id=self._sid_of_row[int(row)], row=int(row),
                 position=int(dec.n_seen[j]), rho=float(dec.rho[j]),
@@ -998,12 +1016,7 @@ class StreamEngine:
                 from repro.parallel import fleet
                 self._drift_states[bi] = fleet.shard_rows(
                     self.mesh, self._drift_states[bi])
-        if self._cost_states is not None and self.mesh is not None:
-            # the eager bounds scatter may have gathered — re-pin
-            from repro.parallel import fleet
-            for bi in touched_buckets:
-                self._cost_states[bi] = fleet.shard_rows(
-                    self.mesh, self._cost_states[bi])
+        self._load_device_meter(sorted(touched_buckets))
         if self._residuals is not None:
             # the re-plan consumed this evidence — restart the residual
             # channel for the processed rows, like the detector
@@ -1046,20 +1059,28 @@ class StreamEngine:
         raise KeyError(row)
 
     def _apply_row_bounds(self, row: int, new_bounds) -> int:
-        """Apply a new boundary vector to one stream everywhere it
-        lives: host meter (re-tiering residents), device cost ledger,
-        and the cost monitor's planned trajectory. Returns the number
-        of relocated residents."""
+        """Apply a new boundary vector to one stream on the host: the
+        meter (re-tiering residents) and the cost monitor's planned
+        trajectory. The caller then reloads the touched buckets' device
+        copies (``_load_device_meter``). Returns the number of relocated
+        residents."""
         bi, jb = self._bucket_of(row)
         ids_arg = (None if self.buckets[bi].engine == "logmem"
-                   else np.asarray(self._states[bi].ids[jb]))
+                   else self._row_ids(bi, jb))
         moved = self.meter.apply_boundaries(row, new_bounds, ids_arg)
-        if self._cost_states is not None:
-            from repro.obs import costs as costs_mod
-            self._cost_states[bi] = costs_mod.set_bucket_bounds(
-                self._cost_states[bi], jb, self.meter.boundaries[row])
+        if self._cost_monitor is not None:
             self._cost_monitor.set_bounds(row, self.meter.boundaries[row])
         return moved
+
+    def _row_ids(self, bi: int, jb: int) -> np.ndarray:
+        """Row ``jb`` of bucket ``bi``'s reservoir ids on the host, read
+        from the one device shard that holds it (a row-sharded array
+        cannot be indexed eagerly)."""
+        for shard in self._states[bi].ids.addressable_shards:
+            lo = shard.index[0].start or 0
+            if lo <= jb < lo + shard.data.shape[0]:
+                return np.asarray(shard.data[jb - lo])
+        raise KeyError((bi, jb))
 
     def _excluded_tier_set(self) -> frozenset:
         """Tiers no plan may place onto right now: failed tiers, plus
@@ -1230,11 +1251,7 @@ class StreamEngine:
                         from repro.parallel import fleet
                         self._drift_states[bi] = fleet.shard_rows(
                             self.mesh, self._drift_states[bi])
-            if self._cost_states is not None and self.mesh is not None:
-                from repro.parallel import fleet
-                for bi in sorted(touched):
-                    self._cost_states[bi] = fleet.shard_rows(
-                        self.mesh, self._cost_states[bi])
+            self._load_device_meter(sorted(touched))
             if self._residuals is not None:
                 self._residuals.reset_where(emask)
             if self._cost_monitor is not None:

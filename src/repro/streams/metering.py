@@ -1,22 +1,26 @@
 """Per-stream transaction metering for the fleet engine, reconciled
 against the analytic per-stream expectations.
 
-Array-of-ledgers layout: one row per stream, so recording a whole bucket's
-update is a handful of vectorized scatter-adds instead of M python ledger
-objects. Streams may place across heterogeneous tier depths: each stream
-carries a non-decreasing boundary vector (padded with +inf up to the
-fleet-wide maximum), and all per-tier arrays are (M, T_max). ``ledger(i)``
+Array-of-ledgers layout: one row per stream. The jitted fleet step
+counts each chunk's per-(stream, tier) transactions on the device
+(``fold``) and ``FleetMeter.record_update`` adds those (M, T) counts to
+the host ledgers, so the host never sees a per-document array. Streams
+may place across heterogeneous tier depths: each stream carries a
+non-decreasing boundary vector (padded with +inf up to the fleet-wide
+maximum), and all per-tier arrays are (M, T_max). ``ledger(i)``
 materializes a classic ``tiers.Ledger`` view for one stream; ``reconcile``
 compares actual write counts to the batched write law
 (``shp.expected_cum_writes_batched`` — eq. 11/12 when batch = 1).
 """
 from __future__ import annotations
 
-from typing import Dict, Sequence
+from typing import Dict, NamedTuple, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from repro.core import compat, shp
+from repro.core import compat, shp, topk
 from repro.core.tiers import Ledger
 
 
@@ -133,81 +137,63 @@ class FleetMeter:
         rows2 = np.broadcast_to(stream_rows[:, None], tiers.shape)
         np.add.at(counter, (rows2[mask], tiers[mask]), 1)
 
-    def record_update(self, stream_rows, doc_ids, wrote,
-                      evicted_ids=None, state_ids=None) -> None:
-        """Account one engine step for a bucket.
+    def device_state(self, stream_rows, pad_m: int) -> "MeterState":
+        """What the step's meter fold (``fold``) needs of these rows, as
+        host arrays padded to ``pad_m`` rows with inert ones (INT32_MAX
+        boundaries, no cascade, nothing observed)."""
+        rows = np.asarray(stream_rows, np.int64)
+        m = rows.shape[0]
+        bounds = np.full((pad_m, self.boundaries.shape[1]), topk.INT32_MAX,
+                         np.int32)
+        bounds[:m] = topk.quantize_boundaries(self.boundaries[rows])
+        floor = np.zeros(pad_m, np.int32)
+        floor[:m] = self.floor[rows]
+        migrate = np.zeros(pad_m, bool)
+        migrate[:m] = self.migrate[rows]
+        observed = np.zeros(pad_m, np.int32)
+        observed[:m] = self.observed[rows]
+        return MeterState(bounds, floor, migrate, observed)
 
-        stream_rows (Mb,): global stream indices of the bucket's rows.
-        doc_ids (Mb, W) int: per-stream local doc indices, -1 = padding.
-        wrote (Mb, W) bool: reservoir-entry mask from the engine.
-        evicted_ids (Mb, K) int, optional: local doc indices evicted by this
-        step (-1 = none), for per-tier delete accounting.
-        state_ids (Mb, K) int, optional: post-step reservoir ids — needed to
-        count the docs that cascade when a migrating stream crosses a
-        boundary.
-        """
-        stream_rows = np.asarray(stream_rows, np.int64)
-        doc_ids = np.asarray(doc_ids)
-        wrote = np.asarray(wrote, bool)
-        np.add.at(self.observed, stream_rows, (doc_ids >= 0).sum(1))
-        # writes: doc index == arrival position, so the static tier is the
-        # write destination with or without a later cascade
-        write_tiers = self._static_tier(stream_rows, doc_ids)
-        write_mask = wrote & (doc_ids >= 0)
-        self._scatter(self.writes, stream_rows, write_tiers, write_mask)
-        self._scatter(self.occupancy, stream_rows, write_tiers, write_mask)
-        if evicted_ids is not None:
-            evicted_ids = np.asarray(evicted_ids)
-            # after a cascade nothing lives below the floor anymore
-            ev_tiers = self._effective_tier(stream_rows, evicted_ids)
-            ev_mask = evicted_ids >= 0
-            self._scatter(self.deletes, stream_rows, ev_tiers, ev_mask)
-            rows2 = np.broadcast_to(stream_rows[:, None], ev_tiers.shape)
-            np.add.at(self.occupancy, (rows2[ev_mask], ev_tiers[ev_mask]), -1)
-        if state_ids is not None:
-            self._maybe_migrate(stream_rows, np.asarray(state_ids))
-        # accrue the rental integral after the step's moves settled
-        self.doc_steps[stream_rows] += (
-            self.occupancy[stream_rows]
-            * (doc_ids >= 0).sum(1).astype(np.int64)[:, None])
-        self.occupancy_hwm[stream_rows] = np.maximum(
-            self.occupancy_hwm[stream_rows], self.occupancy[stream_rows])
-
-    def _maybe_migrate(self, stream_rows, state_ids) -> None:
-        """Fire every boundary whose position the stream just crossed at
-        once: residents hop directly to the highest crossed tier (skipping
-        zero-width tiers, like the simulator and ``TieredStore`` — with
-        W=1 the counts match the simulator exactly)."""
-        b = self.boundaries[stream_rows]  # (Mb, B)
-        crossed = np.where(np.isfinite(b),
-                           self.observed[stream_rows][:, None] >= np.ceil(b),
-                           False)
-        target = crossed.sum(axis=1)  # highest crossed boundary per stream
-        firing = self.migrate[stream_rows] & (target > self.floor[stream_rows])
-        if not np.any(firing):
-            return
-        rows = stream_rows[firing]
-        ids = state_ids[firing]
-        tiers = np.maximum(
-            (ids[:, :, None] >= self.boundaries[rows][:, None, :]).sum(-1),
-            self.floor[rows][:, None])
-        resident = (ids >= 0) & (tiers < target[firing][:, None])
-        np.add.at(self.migrations, rows, resident.sum(1))
-        # hop billing: read each resident out of its source tier, write
-        # it into the target (``SimResult.mig_reads/mig_writes``)
-        rows2 = np.broadcast_to(rows[:, None], tiers.shape)
-        np.add.at(self.mig_reads, (rows2[resident], tiers[resident]), 1)
-        np.add.at(self.mig_writes, (rows, target[firing]),
-                  resident.sum(1))
-        # occupancy: every resident below the target hops into it
-        occ = self.occupancy[rows]
-        tgt = target[firing]
-        below = np.arange(self.n_tiers)[None, :] < tgt[:, None]
-        moved = np.where(below, occ, 0).sum(1)
-        occ = np.where(below, 0, occ)
-        occ[np.arange(rows.shape[0]), tgt] += moved
+    def record_update(self, stream_rows, delta: "MeterDelta") -> None:
+        """Account one engine step for a bucket from the step's per-row
+        counts (``fold``; host arrays, one row per stream of
+        ``stream_rows``, an index array or a slice — a slice updates the
+        ledgers in place, several times faster at a million rows). In
+        order: writes, deletes (at the cascade floor before the hop), the
+        hop of every stream whose floor rose — its residents below the
+        new floor move into it — then the rental integral over the
+        settled occupancy and the high-water mark."""
+        rows = (stream_rows if isinstance(stream_rows, slice)
+                else np.asarray(stream_rows, np.int64))
+        seen = np.asarray(delta.observed, np.int64)
+        writes = np.asarray(delta.writes, np.int64)
+        deletes = np.asarray(delta.deletes, np.int64)
+        self.observed[rows] += seen
+        self.writes[rows] += writes
+        self.deletes[rows] += deletes
+        occ = self.occupancy[rows] + writes - deletes
+        floor = np.asarray(delta.floor, np.int64)
+        hop = floor > self.floor[rows]
+        if hop.any():
+            hrows, target = np.arange(self.m)[rows][hop], floor[hop]
+            moved = np.asarray(delta.migrations, np.int64)[hop]
+            self.migrations[hrows] += moved
+            # hop billing: a read out of each resident's source tier, a
+            # write into the target (``SimResult.mig_reads/mig_writes``)
+            self.mig_reads[hrows] += np.asarray(delta.mig_reads,
+                                                np.int64)[hop]
+            self.mig_writes[hrows, target] += moved
+            h = occ[hop]
+            below = np.arange(self.n_tiers)[None, :] < target[:, None]
+            up = np.where(below, h, 0).sum(1)
+            h = np.where(below, 0, h)
+            h[np.arange(h.shape[0]), target] += up
+            occ[hop] = h
+            self.floor[hrows] = target
         self.occupancy[rows] = occ
-        self.floor[rows] = target[firing]
+        # accrue the rental integral after the step's moves settled
+        self.doc_steps[rows] += occ * seen[:, None]
+        self.occupancy_hwm[rows] = np.maximum(self.occupancy_hwm[rows], occ)
 
     def apply_boundaries(self, row: int, new_bounds, state_ids) -> int:
         """Swap one stream's boundary vector mid-window (online re-plan).
@@ -433,3 +419,69 @@ class FleetMeter:
         led.deletes = self.deletes[i].copy()
         led.migrations = int(self.migrations[i])
         return led
+
+
+# ---------------------------------------------------------------------------
+# the device fold: one chunk's per-(stream, tier) counts, inside the step
+# ---------------------------------------------------------------------------
+
+class MeterState(NamedTuple):
+    """One bucket's device copy of the meter rows the fold reads (rows
+    padded to the shard multiple with inert ones). The host reloads it
+    after a re-plan, an evacuation or a restore; the step advances
+    ``floor`` and ``observed`` itself."""
+
+    bounds: jax.Array  # (Mb, B) i32 — topk.quantize_boundaries
+    floor: jax.Array  # (Mb,) i32 — highest fired cascade boundary
+    migrate: jax.Array  # (Mb,) bool — the stream cascades
+    observed: jax.Array  # (Mb,) i32 — docs metered so far
+
+
+class MeterDelta(NamedTuple):
+    """One chunk's counts per row, what ``record_update`` adds."""
+
+    observed: jax.Array  # (Mb,) live docs (non-finite scores quarantined)
+    writes: jax.Array  # (Mb, T) admits per write (static) tier
+    deletes: jax.Array  # (Mb, T) evictions per tier, floor before the hop
+    migrations: jax.Array  # (Mb,) residents moved by this chunk's hop
+    mig_reads: jax.Array  # (Mb, T) hop reads per source tier
+    floor: jax.Array  # (Mb,) cascade floor after the chunk
+
+
+@jax.named_scope("meter")
+def fold(ms: MeterState, batch_ids, wrote, evicted_ids=None,
+         state_ids=None):
+    """Meter one bucket step on the device (traced inside the fleet
+    step): the chunk's live docs, writes at their static tier, deletes
+    at their tier lifted to the cascade floor, and the cascade of every
+    migrating stream whose observed count passed a boundary above its
+    floor — its residents below the new floor move up (``state_ids``
+    are the post-step reservoir ids). Logmem buckets pass no evicted or
+    state ids: nothing is stored, so nothing deletes or cascades.
+    Returns (the advanced state, the ``MeterDelta``)."""
+    n_tiers = ms.bounds.shape[1] + 1
+    live = batch_ids >= 0
+    seen = live.sum(axis=1, dtype=jnp.int32)
+    observed = ms.observed + seen
+    writes = topk.tier_counts(topk.tiers(batch_ids, ms.bounds),
+                              wrote & live, n_tiers)
+    if evicted_ids is None:
+        none = jnp.zeros_like(writes)
+        return (ms._replace(observed=observed),
+                MeterDelta(seen, writes, none, jnp.zeros_like(seen), none,
+                           ms.floor))
+    floor = ms.floor[:, None]
+    deletes = topk.tier_counts(
+        jnp.maximum(topk.tiers(evicted_ids, ms.bounds), floor),
+        evicted_ids >= 0, n_tiers)
+    # every boundary passed at once: residents hop straight to the
+    # highest (zero-width tiers skipped, as in the simulator)
+    target = (observed[:, None] >= ms.bounds).sum(1, dtype=jnp.int32)
+    firing = ms.migrate & (target > ms.floor)
+    tier = jnp.maximum(topk.tiers(state_ids, ms.bounds), floor)
+    resident = firing[:, None] & (state_ids >= 0) & (tier < target[:, None])
+    new_floor = jnp.where(firing, target, ms.floor)
+    return (ms._replace(observed=observed, floor=new_floor),
+            MeterDelta(seen, writes, deletes,
+                       resident.sum(axis=1, dtype=jnp.int32),
+                       topk.tier_counts(tier, resident, n_tiers), new_floor))

@@ -82,8 +82,9 @@ def test_ingest_chunks_spans_once_per_chunk_in_pipelined_order():
     stage = [e for e in spans if e["name"] == "ingest.stage"]
     assert all(e["attrs"]["bytes"] == 4 * 16 * 8 for e in stage)
     fetch = [e for e in spans if e["name"] == "ingest.fetch"]
-    # wrote (bool) + evicted ids + reservoir ids, per tenant
-    assert all(e["attrs"]["bytes"] == 4 * (16 + 8 * 4 + 8 * 4)
+    # the meter's int32 counts per tenant: observed, migrations and floor,
+    # and writes, deletes and hop reads per tier
+    assert all(e["attrs"]["bytes"] == 4 * 4 * (3 + 3 * eng.meter.n_tiers)
                for e in fetch)
 
 
@@ -215,14 +216,15 @@ def test_lowered_step_carries_named_scopes():
     batches = eng._stage_batches(_dense(eng, 0))
     text = eng._step.lower(
         tuple(eng._states), batches, tuple(eng._drift_states),
-        eng._metrics_state, ()).compile().as_text()
+        eng._metrics_state, (), tuple(eng._meter_states)
+    ).compile().as_text()
     # a scope entered under vmap reads "vmap(<scope>)" in the name stack
     stacks = [[re.sub(r"^vmap\((.+)\)$", r"\1", part)
                for part in n.split("/")]
               for n in set(re.findall(r'op_name="([^"]*)"', text))]
     scopes = {part for st in stacks for part in st}
     for scope in ("member", "filter", "merge", "evicted", "logmem",
-                  "drift", "obs"):
+                  "drift", "obs", "meter"):
         assert scope in scopes, scope
     assert any("filter" in st and "member" in st for st in stacks)
 

@@ -112,8 +112,9 @@ def test_planner_with_plan_solve_compiles(one_chip, compiled_kernels,
 
 @pytest.mark.parametrize("kernel", [False, True])
 def test_fleet_step_compiles(one_chip, compiled_kernels, kernel):
-    """The donating fleet step with device metrics over an exact bucket
-    and a logmem bucket: the default jnp path, and the Pallas filter."""
+    """The donating fleet step with device metrics and the meter fold
+    over an exact bucket and a logmem bucket: the default jnp path, and
+    the Pallas filter."""
     step = engine._make_step(kernel, 512, bucket_ks=(K, LK),
                              with_metrics=True, donate=True,
                              bucket_engines=("exact", "logmem"))
@@ -128,7 +129,14 @@ def test_fleet_step_compiles(one_chip, compiled_kernels, kernel):
     mstate = jax.tree_util.tree_map(
         lambda x: _spec(one_chip, np.shape(x), jnp.asarray(x).dtype),
         metrics.init())
-    c = step.lower(states, batches, (), mstate, ()).compile()
+    from repro.streams import metering
+    meters = tuple(
+        metering.MeterState(
+            bounds=_spec(one_chip, (m, 2), jnp.int32),
+            floor=_spec(one_chip, (m,), jnp.int32),
+            migrate=_spec(one_chip, (m,), jnp.bool_),
+            observed=_spec(one_chip, (m,), jnp.int32)) for m in (M, LM))
+    c = step.lower(states, batches, (), mstate, (), meters).compile()
     assert (_kernels(c) > 0) == kernel
     mem = c.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes

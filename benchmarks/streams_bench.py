@@ -229,9 +229,8 @@ def _sharded_step_rows(emit, rng):
     shards = fleet.n_shards(mesh)
     for m, w in SHARD_SWEEP:
         reps, rounds = (10, 8) if m <= 100_000 else (2, 2)
-        step1 = engine._make_step(False, 512, update_path="auto")
-        stepd = engine._make_step(False, 512, update_path="auto",
-                                  mesh=mesh)
+        step1 = engine._make_step(False, 512)
+        stepd = engine._make_step(False, 512, mesh=mesh)
         sc = rng.standard_normal((m, w)).astype(np.float32)
         ids = np.tile(np.arange(w, dtype=np.int32), (m, 1))
         st = engine.init(m, K)
@@ -269,8 +268,7 @@ def run(emit):
         sc = jnp.asarray(rng.standard_normal((m, BATCH)), jnp.float32)
         ids = jnp.tile(jnp.arange(BATCH, dtype=jnp.int32), (m, 1))
         # headline row first: the jnp filter+merge is what StreamEngine
-        # ships on wide batches (update_path="auto") — it beat the fused
-        # sort-merge at every M, so the engine now dispatches to it
+        # runs on exact buckets
         us = _time(filt, state, sc, ids)
         emit(f"streams.filtered_update_m{m}_k{K}_b{BATCH}", us,
              f"{m * BATCH / us * 1e6:.0f} docs/s filter+merge "
@@ -278,7 +276,7 @@ def run(emit):
         us = _time(upd, state, sc, ids)
         emit(f"streams.update_m{m}_k{K}_b{BATCH}", us,
              f"{m * BATCH / us * 1e6:.0f} docs/s vmap sort-merge "
-             f"(legacy fused path; narrow batches only)")
+             f"(engine.update)")
         if on_tpu:
             us = _time(pal, state, sc, ids)
             emit(f"streams.filtered_update_pallas_m{m}_k{K}_b{BATCH}", us,

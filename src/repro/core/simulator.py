@@ -116,7 +116,7 @@ def simulate(scores: np.ndarray, k: int, policy: Policy,
                          f"the cost model has {nt.t}")
 
     # min-heap of (score, -index): root = weakest member (ties: latest doc
-    # is weakest, i.e. earlier doc wins, matching topk.update's lexsort).
+    # is weakest, i.e. earlier doc wins, matching topk.update's id order).
     heap: list[tuple[float, int]] = []
     tier_of_doc: dict[int, int] = {}
     write_index: dict[int, int] = {}

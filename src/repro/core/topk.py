@@ -88,12 +88,23 @@ def member(needles: jax.Array, haystack: jax.Array) -> jax.Array:
     return jnp.zeros((n,), bool).at[slot].set(hit, mode="drop")
 
 
-def _merge_sorted(scores: jax.Array, ids: jax.Array, k: int):
-    """Top-k of (scores, ids) with lower-id tie-break; returns sorted desc."""
-    # lexsort: primary = -score, secondary = id  → stable deterministic order.
-    order = jnp.lexsort((ids, -scores))
-    top = order[:k]
-    return scores[top], ids[top]
+def ranks_at_or_above(scores: jax.Array, ids: jax.Array,
+                      bar_scores: jax.Array, bar_ids: jax.Array) -> jax.Array:
+    """Whether each (score, id) entry ranks at or above the entry
+    (``bar_scores``, ``bar_ids``) in the merge's order: score descending,
+    then id ascending. Broadcasts, so a reservoir's K-th entry tests a
+    whole row."""
+    return (scores > bar_scores) | ((scores == bar_scores) & (ids <= bar_ids))
+
+
+def first_k(scores: jax.Array, ids: jax.Array, k: int):
+    """The k first of (scores, ids) in the merge's order (score
+    descending, then id ascending), sorted so. One sort takes both as its
+    keys and carries them, so nothing is read back through an index (a
+    gather on a TPU). Its comparator holds -0.0 and +0.0 equal, as
+    ``ranks_at_or_above``'s compares do."""
+    neg, ids = jax.lax.sort((-scores, ids), num_keys=2)
+    return -neg[:k], ids[:k]
 
 
 def update(state: ReservoirState, batch_scores: jax.Array,
@@ -122,16 +133,14 @@ def update(state: ReservoirState, batch_scores: jax.Array,
         cand_ids = jnp.where(resident, -1, batch_ids)
     all_scores = jnp.concatenate([state.scores, cand_scores])
     all_ids = jnp.concatenate([state.ids, cand_ids])
-    order = jnp.lexsort((all_ids, -all_scores))
-    top = order[:k]
-    # positional membership, not id membership: an id collision with a
-    # resident entry must not report a write for the colliding batch element.
-    selected = jnp.zeros(all_ids.shape, dtype=bool).at[top].set(True)
-    wrote = selected[k:] & (cand_ids >= 0)
-    new_state = ReservoirState(
-        scores=all_scores[top], ids=all_ids[top],
-        seen=state.seen + batch_ids.shape[0],
-    )
+    scores, ids = first_k(all_scores, all_ids, k)
+    # written iff it ranks at or above the new K-th entry: valid ids are
+    # unique among the merged entries (a re-observed resident enters as a
+    # (-inf, -1) pad and never writes), so rank and position agree
+    wrote = (cand_ids >= 0) & ranks_at_or_above(cand_scores, cand_ids,
+                                                scores[-1:], ids[-1:])
+    new_state = ReservoirState(scores=scores, ids=ids,
+                               seen=state.seen + batch_ids.shape[0])
     return new_state, wrote
 
 
@@ -152,8 +161,8 @@ def dropped(old: ReservoirState, new: ReservoirState) -> jax.Array:
     because valid ids are unique among the merged entries (resident
     re-observations enter as (-inf, -1) pads) and scores are never NaN.
     Works on stacked states too (the last axis is the reservoir)."""
-    bar_s, bar_i = new.scores[..., -1:], new.ids[..., -1:]
-    kept = (old.scores > bar_s) | ((old.scores == bar_s) & (old.ids <= bar_i))
+    kept = ranks_at_or_above(old.scores, old.ids, new.scores[..., -1:],
+                             new.ids[..., -1:])
     return (old.ids >= 0) & ~kept
 
 
@@ -164,7 +173,7 @@ def merge(a: ReservoirState, b: ReservoirState) -> ReservoirState:
     k = a.scores.shape[0]
     scores = jnp.concatenate([a.scores, b.scores])
     ids = jnp.concatenate([a.ids, b.ids])
-    s, i = _merge_sorted(scores, ids, k)
+    s, i = first_k(scores, ids, k)
     return ReservoirState(scores=s, ids=i, seen=a.seen + b.seen)
 
 

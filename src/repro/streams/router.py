@@ -7,11 +7,9 @@ vectorized sort-merge updates all of them. A mixed ingest batch
 (stream_id, score, doc_id) triples in arbitrary order — is grouped by
 bucket, then scattered into ``(M_bucket, W)`` matrices padded with
 ``(-inf, -1)``; each stream's row is ordered by doc id (= stream
-position), which makes routing deterministic and guarantees the
-id-increasing order the kernel-filtered engine path needs for its
-tie-break to match the exact merge. ``W`` is rounded up to a power of two
-to bound the number of distinct shapes the jitted engine step compiles
-for.
+position), which makes routing deterministic. ``W`` is rounded up to a
+power of two to bound the number of distinct shapes the jitted engine
+step compiles for.
 """
 from __future__ import annotations
 

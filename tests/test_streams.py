@@ -12,6 +12,8 @@ from repro.kernels.batched_topk import ops as btk_ops
 from repro.kernels.batched_topk import ref as btk_ref
 from repro.streams import StreamEngine, StreamSpec, engine, planner, router
 
+import merge_reference
+
 
 # ---------------------------------------------------------------------------
 # core.topk regressions (satellites: wrote-mask collision, merge algebra)
@@ -43,6 +45,22 @@ def test_update_id_collision_never_duplicates_slot():
     ids = np.asarray(state.ids)
     assert np.sum(ids == 10) == 1
     assert float(state.scores[ids.tolist().index(10)]) == 2.0
+
+
+def test_signed_zero_scores_tie_by_id():
+    """-0.0 and +0.0 are one score to the merge's sort and to the rank
+    compares alike: the lower id wins, and the write mask and evictions
+    read by rank agree with the merge."""
+    state = topk.init(2)
+    state, _ = topk.update(state, jnp.array([1.0, 0.0]),
+                           jnp.array([5, 6], jnp.int32))
+    new, wrote = topk.update(state, jnp.array([-0.0]),
+                             jnp.array([2], jnp.int32))
+    assert np.asarray(new.ids).tolist() == [5, 2]
+    assert np.asarray(new.scores).tolist() == [1.0, 0.0]
+    assert np.asarray(wrote).tolist() == [True]
+    assert (np.asarray(topk.dropped(state, new)).tolist()
+            == np.asarray(topk.evicted(state, new)).tolist() == [False, True])
 
 
 def _random_state(rng, k, lo, hi):
@@ -155,46 +173,68 @@ def test_filtered_update_drops_resident_reobservation(use_pallas):
     np.testing.assert_array_equal(np.asarray(w_plain), np.asarray(w_filt))
 
 
+@pytest.mark.parametrize("order", ["increasing", "permuted"])
 @pytest.mark.parametrize("use_pallas", [True, False])
-def test_filtered_update_equals_plain_update(use_pallas):
+def test_filtered_update_equals_plain_update(use_pallas, order):
+    """Bit-equal states and write masks, also when a stream's ids arrive
+    in no order and scores tie: the cut and the merge both break ties by
+    id, and the scan keeps candidates tied with the bar."""
     rng = np.random.default_rng(4)
     m, k, w = 6, 16, 256
+    ids_all = np.tile(np.arange(3 * w, dtype=np.int32), (m, 1))
+    if order == "permuted":
+        ids_all = rng.permuted(ids_all, axis=1)
     st_plain = engine.init(m, k)
     st_filt = engine.init(m, k)
     for step in range(3):
-        sc = jnp.asarray(rng.standard_normal((m, w)), jnp.float32)
-        ids = jnp.tile(jnp.arange(step * w, (step + 1) * w, dtype=jnp.int32),
-                       (m, 1))
+        sc = (rng.standard_normal((m, w)) if order == "increasing"
+              else rng.integers(0, 8, (m, w)))
+        sc = jnp.asarray(sc, jnp.float32)
+        ids = jnp.asarray(ids_all[:, step * w:(step + 1) * w])
         st_plain, w_plain = engine.update(st_plain, sc, ids)
         st_filt, w_filt = engine.filtered_update(st_filt, sc, ids,
                                                  block_n=128,
                                                  use_pallas=use_pallas)
         np.testing.assert_array_equal(np.asarray(w_plain),
                                       np.asarray(w_filt))
-        np.testing.assert_array_equal(np.sort(np.asarray(st_plain.ids), 1),
-                                      np.sort(np.asarray(st_filt.ids), 1))
+        for a, b in zip(st_plain, st_filt):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+MERGE_CASES = ["unfull", "full", "reobserved", "padded", "tied", "neginf",
+               "permuted"]
 
 
 def _eviction_batch(case, rng, state, step, m, w):
-    """One batch per stream for ``case``: increasing fresh ids, plus
-    residents re-sent at a top score, pads, or tied scores."""
-    scores = (rng.integers(0, 3, (m, w)) if case == "tied"
+    """One batch per stream for ``case`` (of three chunks): increasing
+    fresh ids, plus residents re-sent at a top score, pads, tied scores,
+    valid ids at -inf, two live documents a chunk (``unfull``), or tied
+    scores with ids in no order (``permuted``)."""
+    tied = case in ("tied", "permuted")
+    scores = (rng.integers(0, 3, (m, w)) if tied
               else rng.standard_normal((m, w))).astype(np.float32)
     ids = np.tile(np.arange(step * w, (step + 1) * w, dtype=np.int32),
                   (m, 1))
+    if case == "permuted":
+        order = np.random.default_rng(m * w).permuted(
+            np.tile(np.arange(3 * w, dtype=np.int32), (m, 1)), axis=1)
+        ids = order[:, step * w:(step + 1) * w].copy()
     if case == "reobserved" and step:
         res = np.asarray(state.ids)
         ids[:, 0], scores[:, 0] = res[:, 0], 100.0
     if case == "padded":
         ids[:, w // 2:], scores[:, w // 2:] = -1, -np.inf
         ids[0], scores[0] = -1, -np.inf
+    if case == "unfull":
+        ids[:, 2:], scores[:, 2:] = -1, -np.inf
+    if case == "neginf":
+        scores[:, ::3] = -np.inf
     return jnp.asarray(scores), jnp.asarray(ids)
 
 
 @pytest.mark.parametrize("path", ["update", "filtered_jnp",
                                   "filtered_pallas"])
-@pytest.mark.parametrize("case", ["unfull", "full", "reobserved", "padded",
-                                  "tied"])
+@pytest.mark.parametrize("case", MERGE_CASES)
 def test_step_evictions_by_rank_equal_id_search(path, case):
     """The step's evictions (``dropped_ids``, read off the merge's order)
     equal the id search ``evicted_ids`` after each update path."""
@@ -217,6 +257,75 @@ def test_step_evictions_by_rank_equal_id_search(path, case):
         evicted += int((want >= 0).sum())
         st = new
     assert (evicted == 0) == (case == "unfull")
+
+
+def _single_update(st, sc, ids):
+    new, wrote = jax.vmap(topk.update)(engine._as_single(st), sc, ids)
+    return engine.BatchedReservoirState(new.scores, new.ids, new.seen), wrote
+
+
+def _single_reference(st, sc, ids):
+    new, wrote = jax.vmap(merge_reference.topk_update)(
+        engine._as_single(st), sc, ids)
+    return engine.BatchedReservoirState(new.scores, new.ids, new.seen), wrote
+
+
+@pytest.mark.parametrize("w", [4, 8, 16])
+@pytest.mark.parametrize("path", ["topk", "update", "filtered_jnp",
+                                  "filtered_pallas"])
+@pytest.mark.parametrize("case", MERGE_CASES)
+def test_update_paths_equal_positional_reference(case, path, w):
+    """Each update path, merging by sort keys and reading its write mask
+    by rank, is bit-equal to the positional merge (``merge_reference``:
+    lexsort, gathers, scatters) in survivors, scores, write masks and
+    ``seen``, with W below, at and above K. The filtered paths also equal
+    the positional filter, save where ids arrive in no order (there that
+    form cuts ties by batch position)."""
+    rng = np.random.default_rng(w)
+    m, k = 4, 8
+    fns = {"topk": _single_update, "update": engine.update,
+           "filtered_jnp": lambda *a: engine.filtered_update(
+               *a, block_n=128, use_pallas=False),
+           "filtered_pallas": lambda *a: engine.filtered_update(
+               *a, block_n=128, use_pallas=True)}
+    refs = [_single_reference if path == "topk"
+            else merge_reference.engine_update]
+    if path.startswith("filtered") and case != "permuted":
+        refs.append(lambda *a: merge_reference.filtered_update(
+            *a, use_pallas=path == "filtered_pallas"))
+    st = engine.init(m, k)
+    for step in range(3):
+        sc, ids = _eviction_batch(case, rng, st, step, m, w)
+        new, wrote = fns[path](st, sc, ids)
+        for ref in refs:
+            want, want_wrote = ref(st, sc, ids)
+            np.testing.assert_array_equal(np.asarray(wrote),
+                                          np.asarray(want_wrote))
+            for a, b in zip(new, want):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        st = new
+    if case == "unfull":
+        assert not (np.asarray(st.ids) >= 0).all(axis=1).any()
+
+
+@pytest.mark.parametrize("path", ["update", "filtered_update", "merge"])
+def test_exact_merge_lowers_without_gather_or_scatter(path):
+    """At a compare-method shape the exact merge moves no entry through an
+    index: its lowered program holds sorts, and no gather or scatter."""
+    m, k, w = 8, 1024, 1024
+    assert topk.member_method(w, k) == "compare"
+    st = jax.eval_shape(lambda: engine.init(m, k))
+    batch = (jax.ShapeDtypeStruct((m, w), jnp.float32),
+             jax.ShapeDtypeStruct((m, w), jnp.int32))
+    fn, args = {
+        "update": (engine.update, (st, *batch)),
+        "filtered_update": (lambda *a: engine.filtered_update(
+            *a, use_pallas=False), (st, *batch)),
+        "merge": (engine.merge, (st, st))}[path]
+    text = jax.jit(fn).lower(*args).as_text()
+    assert "stablehlo.sort" in text
+    assert "stablehlo.gather" not in text
+    assert "stablehlo.scatter" not in text
 
 
 def test_engine_bit_matches_simulator_replays():
@@ -247,9 +356,8 @@ def test_engine_bit_matches_simulator_replays():
 
 def test_engine_kernel_filter_matches_plain_on_tied_scores():
     """Quantized scores produce ties; shuffled ingest through the
-    kernel-filtered engine must still match the exact path (the router
-    id-orders each row so lax.top_k's positional tie-break equals the
-    merge's lowest-id tie-break)."""
+    kernel-filtered engine must still match the exact path (both break
+    ties by the lowest id)."""
     rng = np.random.default_rng(11)
     m, k, docs, batch = 3, 3, 24, 4
     specs_a = [StreamSpec(stream_id=i, k=k, r=float(docs)) for i in range(m)]

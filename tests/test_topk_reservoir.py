@@ -118,7 +118,8 @@ def test_tier_of_threshold():
     assert list(np.asarray(t)) == [0, 0, 1, 1]
 
 
-EVICTION_CASES = ("unfull", "full", "reobserved", "padded", "tied")
+EVICTION_CASES = ("unfull", "full", "reobserved", "padded", "tied",
+                  "neginf", "permuted")
 
 
 def _eviction_case(case, m=6, k=8, w=12, seed=7):
@@ -130,13 +131,18 @@ def _eviction_case(case, m=6, k=8, w=12, seed=7):
     if case == "unfull":
         w = k - fill - 1  # the batch fits: no row fills up
     draw = ((lambda n: rng.integers(0, 3, (m, n)).astype(np.float32))
-            if case == "tied" else
+            if case in ("tied", "permuted") else
             (lambda n: rng.standard_normal((m, n)).astype(np.float32)))
     state = jax.vmap(lambda _: topk.init(k))(jnp.arange(m))
-    ids0 = np.tile(np.arange(fill, dtype=np.int32), (m, 1))
+    ids = np.tile(np.arange(fill + w, dtype=np.int32), (m, 1))
+    if case == "permuted":
+        # ids in no order: the batch brings ids below the residents'
+        ids = rng.permuted(ids, axis=1)
+    ids0, ids = ids[:, :fill], ids[:, fill:]
     state, _ = upd(state, jnp.asarray(draw(fill)), jnp.asarray(ids0))
     scores = draw(w)
-    ids = np.tile(np.arange(fill, fill + w, dtype=np.int32), (m, 1))
+    if case == "neginf":
+        scores[:, ::3] = -np.inf  # valid ids at -inf never enter
     if case == "reobserved":
         # every row re-sends two of its residents, one at a top score
         res = np.asarray(state.ids)
@@ -159,7 +165,7 @@ def test_dropped_equals_evicted(case):
         np.asarray(jax.vmap(topk.dropped)(old, new)), want)
     if case == "unfull":
         assert not want.any()
-    if case in ("full", "tied", "reobserved"):
+    if case in ("full", "tied", "reobserved", "neginf", "permuted"):
         assert want.any()
 
 

@@ -144,6 +144,22 @@ def test_fleet_step_compiles(one_chip, compiled_kernels, kernel):
     assert used < 16e9, used
 
 
+@pytest.mark.parametrize("w", [64, 512])
+def test_narrow_filtered_update_compiles(one_chip, compiled_kernels, w):
+    """Narrow batches (W < K) take the filtered update too: its Pallas
+    scan compiles below one column block, and the merge moves no entry
+    through an index."""
+    st = jax.tree_util.tree_map(
+        lambda x: _spec(one_chip, x.shape, x.dtype),
+        jax.eval_shape(lambda: engine.init(M, K)))
+    c = jax.jit(lambda *a: engine.filtered_update(*a, use_pallas=True)
+                ).lower(st, _spec(one_chip, (M, w)),
+                        _spec(one_chip, (M, w), jnp.int32)).compile()
+    assert _kernels(c)
+    assert " gather(" not in c.as_text()
+    assert " scatter(" not in c.as_text()
+
+
 @pytest.mark.parametrize("rows,n,h,method", [(M, W, K, "compare"),
                                               (LM, LK // 2, LK // 2, "sort")])
 def test_member_compiles_without_loop(one_chip, rows, n, h, method):

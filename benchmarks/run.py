@@ -33,13 +33,6 @@ CHECK_TOLERANCE = 0.30
 _SHARDED_RE = re.compile(r"^(?P<base>.+)\.sharded_d(?P<d>\d+)$")
 SHARD_FLOOR_FULL = 2.0
 
-# cost-ledger overhead ceiling: each ``engine_step_costobs_*`` row is
-# paired with its SAME-RUN ``engine_step_obs_*`` twin (identical fleet,
-# batch, and interleaved rounds — the delta is the device CostState
-# fold alone) and must stay within 5% of it
-_COSTOBS_RE = re.compile(r"^streams\.engine_step_costobs_(?P<size>.+)$")
-COSTOBS_TOLERANCE = 0.05
-
 # chunk-boundary checkpointing ceiling: each ``engine_step_ckpt_*`` row
 # is paired with its SAME-RUN ``engine_step_ckptoff_*`` twin (identical
 # fleet, chunks, interleaved rounds — the delta is the snapshot + async
@@ -187,31 +180,6 @@ def check_regressions(fresh: dict, baseline_dir: str = ".",
                 if entry["status"] == "sharded_slow":
                     regressions.append(entry)
             diff.append(entry)
-        # cost-ledger rows: same-run pairing against the obs twin — the
-        # device CostState fold must stay within COSTOBS_TOLERANCE of
-        # the metrics-only step (min-of-interleaved-rounds on both
-        # sides, so the comparison carries no cross-machine assumptions)
-        for row in rows:
-            match = _COSTOBS_RE.match(row["name"])
-            if match is None:
-                continue
-            entry = {"name": row["name"], "us_new": row["us_per_call"],
-                     "guarded": True, "tol": COSTOBS_TOLERANCE}
-            ref = by_name.get(
-                f"streams.engine_step_obs_{match.group('size')}")
-            if ref is None or not ref["us_per_call"]:
-                entry["status"] = "missing_obs_ref"
-                regressions.append(entry)
-            else:
-                overhead = row["us_per_call"] / ref["us_per_call"] - 1.0
-                entry["us_obs"] = ref["us_per_call"]
-                entry["overhead"] = overhead
-                entry["status"] = ("costobs_slow"
-                                   if overhead > COSTOBS_TOLERANCE
-                                   else "ok")
-                if entry["status"] == "costobs_slow":
-                    regressions.append(entry)
-            diff.append(entry)
         # checkpointing rows: same-run pairing against the no-checkpoint
         # twin — the chunk-boundary snapshot + async write handoff must
         # stay within CKPT_TOLERANCE of the bare ingest loop
@@ -278,14 +246,6 @@ def check_regressions(fresh: dict, baseline_dir: str = ".",
                   f"{entry['speedup']:.2f}x vs same-run ref, floor "
                   f"{entry['floor']:.2f}x "
                   f"({entry['effective_cores']} effective core(s))")
-        elif entry["status"] == "missing_obs_ref":
-            print(f"  MISSING same-run engine_step_obs twin for "
-                  f"{entry['name']}")
-        elif entry["status"] == "costobs_slow":
-            print(f"  COSTOBS-SLOW {entry['name']}: "
-                  f"{entry['overhead']:+.1%} over the same-run obs twin "
-                  f"({entry['us_new']:.1f}us vs {entry['us_obs']:.1f}us), "
-                  f"ceiling {entry['tol']:.0%}")
         elif entry["status"] == "missing_ckptoff_ref":
             print(f"  MISSING same-run engine_step_ckptoff twin for "
                   f"{entry['name']}")

@@ -48,46 +48,37 @@ _time = timers.time_jax  # the shared device-dispatch discipline
 
 
 def _engine_step_pair(emit, m, rng):
-    """The full fleet-engine jitted step, telemetry off vs on vs on-with-
-    costs: the row triple the obs layer's overhead budgets are checked
-    against (same routed batch, same bucket structure; the obs variant
-    carries the device ``MetricsState`` accumulators through the step,
-    the costobs variant additionally folds the per-(stream, tier)
-    ``CostState`` ledger — run.py --check holds costobs within 5% of
-    obs, same-run)."""
+    """The full fleet-engine jitted step, telemetry off vs on: the row
+    pair the obs layer's overhead is read from (same routed batch, same
+    bucket structure; the obs variant carries the device
+    ``MetricsState`` accumulators through the step)."""
     specs = [engine.StreamSpec(stream_id=i, k=K, r=4096.0)
              for i in range(m)]
     sids = np.repeat(np.arange(m), BATCH)
     dids = np.tile(np.arange(BATCH), m)
     sc = rng.standard_normal(m * BATCH)
-    labels = {"": "telemetry off", "_obs": "device metrics on",
-              "_costobs": "metrics + cost ledger on"}
+    labels = {"": "telemetry off", "_obs": "device metrics on"}
     variants = []
     for suffix, obs in (
             ("", None),
-            ("_obs", Observability(ObsConfig(residuals=False))),
-            ("_costobs", Observability(ObsConfig(residuals=False,
-                                                 costs=True)))):
+            ("_obs", Observability(ObsConfig(residuals=False)))):
         eng = engine.StreamEngine(specs, obs=obs)
         routed = eng.router.route(sids, sc, dids)
         batches = tuple((jnp.asarray(s), jnp.asarray(i)) for s, i in routed)
         mstate = (eng._metrics_state
                   if eng._metrics_state is not None else ())
-        cstates = (tuple(eng._cost_states)
-                   if eng._cost_states is not None else ())
-        variants.append((suffix, eng, batches, mstate, cstates,
-                         [float("inf")]))
-    # interleaved min-of-rounds: the deltas inside the triple are the obs
-    # overhead budgets, so all variants must sample the same machine
+        variants.append((suffix, eng, batches, mstate, [float("inf")]))
+    # interleaved min-of-rounds: the delta inside the pair is the obs
+    # overhead, so both variants must sample the same machine
     # weather — alternating rounds and keeping the min is robust to the
     # contention spikes a single long rep window averages in
     for _ in range(32):
-        for _, eng, batches, mstate, cstates, best in variants:
+        for _, eng, batches, mstate, best in variants:
             best[0] = min(best[0],
                           _time(eng._step, tuple(eng._states), batches,
-                                (), mstate, cstates,
-                                tuple(eng._meter_states), reps=25))
-    for suffix, _, _, _, _, best in variants:
+                                (), mstate, tuple(eng._meter_states),
+                                reps=25))
+    for suffix, _, _, _, best in variants:
         us = best[0]
         emit(f"streams.engine_step{suffix}_m{m}_k{K}_b{BATCH}", us,
              f"{m * BATCH / us * 1e6:.0f} docs/s fleet step "
@@ -124,7 +115,7 @@ def _backend_rows(emit, rng):
             for _, eng, best in variants:
                 best[0] = min(best[0],
                               _time(eng._step, tuple(eng._states), batches,
-                                    (), (), (), tuple(eng._meter_states),
+                                    (), (), tuple(eng._meter_states),
                                     reps=reps))
         for backend, eng, best in variants:
             us = best[0]
@@ -237,11 +228,11 @@ def _sharded_step_rows(emit, rng):
         sh = fleet.row_sharding(mesh)
         variants = [
             ("ref1", step1, ((st,), ((jnp.asarray(sc),
-                                      jnp.asarray(ids)),), (), (), (), ())),
+                                      jnp.asarray(ids)),), (), (), ())),
             (f"sharded_d{shards}", stepd,
              (((fleet.shard_rows(mesh, st)),),
               ((jax.device_put(sc, sh), jax.device_put(ids, sh)),),
-              (), (), (), ())),
+              (), (), ())),
         ]
         best = {name: float("inf") for name, _, _ in variants}
         for _ in range(rounds):  # interleaved: same machine weather

@@ -261,7 +261,7 @@ def census(eng, S):
         for st, w in ((s, S["lw"] if b.engine == "logmem" else S["w"])
                       for s, b in zip(states, eng.buckets)))
     found = kernels_in(eng._donating_step, states, batches, (),
-                       eng._metrics_state, (), tuple(eng._meter_states))
+                       eng._metrics_state, tuple(eng._meter_states))
     f32 = jax.ShapeDtypeStruct((8192, 3), np.float32)
     v32 = jax.ShapeDtypeStruct((8192,), np.float32)
     found |= kernels_in(shp_jax._plan_jit, f32, f32, f32, v32, v32, v32,

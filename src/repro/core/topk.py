@@ -144,23 +144,17 @@ def update(state: ReservoirState, batch_scores: jax.Array,
     return new_state, wrote
 
 
-def evicted(old: ReservoirState, new: ReservoirState) -> jax.Array:
-    """Mask over ``old.ids`` of entries no longer present in ``new`` —
-    the documents whose storage can be freed (overwritten, paper §VI).
-    Searches ``new.ids`` for every old id (``member``), for any pair of
-    states; the engine step reads the same mask off the merge with
-    ``dropped``."""
-    return (old.ids >= 0) & ~member(old.ids, new.ids)
-
-
 def dropped(old: ReservoirState, new: ReservoirState) -> jax.Array:
-    """``evicted`` for a ``new`` that ``update`` made from ``old``,
-    without a search: the merge keeps the K first entries in its
-    order (score descending, then id ascending), so an old entry survives
-    iff it ranks at or above ``new``'s K-th entry. Equal to ``evicted``
-    because valid ids are unique among the merged entries (resident
-    re-observations enter as (-inf, -1) pads) and scores are never NaN.
-    Works on stacked states too (the last axis is the reservoir)."""
+    """Mask over ``old.ids`` of entries evicted by the ``update`` that
+    made ``new`` from ``old`` — the documents whose storage can be freed
+    (overwritten, paper §VI) — read off the merge's order without an id
+    search: the merge keeps the K first entries in its order (score
+    descending, then id ascending), so an old entry survives iff it
+    ranks at or above ``new``'s K-th entry. That is exactly the set of
+    old ids absent from ``new``, because valid ids are unique among the
+    merged entries (resident re-observations enter as (-inf, -1) pads)
+    and scores are never NaN. Works on stacked states too (the last axis
+    is the reservoir)."""
     kept = ranks_at_or_above(old.scores, old.ids, new.scores[..., -1:],
                              new.ids[..., -1:])
     return (old.ids >= 0) & ~kept

@@ -7,9 +7,9 @@
 #   residuals — realized vs closed-form expectation + z-scores for the
 #               write/occupancy/latency laws; ResidualMonitor alert
 #               channel (concentration-bound, fires at or before CUSUM)
-#   costs     — device-side CostState ledger + closed-form expected-cost
-#               trajectories, per-tenant regret, and budget burn-rate
-#               alerts (CostMonitor)
+#   costs     — per-tenant bills priced from the fleet meter's counts,
+#               closed-form expected-cost trajectories, regret, and
+#               budget burn-rate alerts (CostMonitor)
 #   trace     — span/event timeline with a stable JSONL schema (each
 #               span names its parent) and jax.profiler TraceAnnotation
 #               integration
@@ -62,22 +62,23 @@ class ObsConfig:
     (per-chunk host update from the meter drain). ``residual_trigger``:
     feed residual alerts to the ``Replanner`` as an earlier trigger
     (requires the engine's ``replan=`` config; alerts then reset like
-    detector evidence). ``costs``: carry the device ``CostState``
-    ledger through the jitted step and maintain the ``CostMonitor``
-    cost-residual / budget burn-rate alert channel (``obs.costs``).
-    ``cost_trigger``: union cost/burn alerts into the re-plan trigger
-    exactly like ``residual_trigger``. ``budget_factor``: overspend
-    budget — burn alerts require realized > threshold × budget_factor ×
-    planned on both windows of a ``burn_windows`` (long, short,
-    threshold) pair. ``events_path``: stream the event log to this
-    JSONL file. ``profiler_annotations``: mirror spans into the JAX
-    profiler timeline. ``trace_ingest``: record the per-chunk ingest
-    spans — ``ingest`` around a routed batch, and for every chunk
-    ``ingest.route`` (routed batches), ``ingest.stage``,
-    ``ingest.dispatch``, ``ingest.wait``, ``ingest.fetch``,
-    ``ingest.meter``, ``ingest.monitors`` and ``ingest.checkpoint``
-    (with a checkpoint hook), each tagged with its ``chunk`` (point
-    events for replan/admission/violations are always recorded).
+    detector evidence). ``costs``: price the per-tenant bill from the
+    fleet meter's counts (``obs.costs``; nothing is added to the jitted
+    step) and maintain the ``CostMonitor`` cost-residual / budget
+    burn-rate alert channel. ``cost_trigger``: union cost/burn alerts
+    into the re-plan trigger exactly like ``residual_trigger``.
+    ``budget_factor``: overspend budget — burn alerts require realized >
+    threshold × budget_factor × planned on both windows of a
+    ``burn_windows`` (long, short, threshold) pair. ``events_path``:
+    stream the event log to this JSONL file. ``profiler_annotations``:
+    mirror spans into the JAX profiler timeline. ``trace_ingest``:
+    record the per-chunk ingest spans — ``ingest`` around a routed
+    batch, and for every chunk ``ingest.route`` (routed batches),
+    ``ingest.stage``, ``ingest.dispatch``, ``ingest.wait``,
+    ``ingest.fetch``, ``ingest.meter``, ``ingest.monitors`` and
+    ``ingest.checkpoint`` (with a checkpoint hook), each tagged with
+    its ``chunk`` (point events for replan/admission/violations are
+    always recorded).
     """
 
     metrics: bool = True

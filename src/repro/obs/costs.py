@@ -1,4 +1,4 @@
-"""Live cost attribution: device-resident per-stream cost ledgers,
+"""Live cost attribution: per-stream bills priced from the fleet meter,
 closed-form expected-cost trajectories, regret, and budget burn alerts.
 
 The paper's objective *is* cost — expected write + storage + read +
@@ -10,18 +10,16 @@ on raw gauges.
 
 Three pieces:
 
-* ``CostState`` — a tiny per-bucket pytree carried through the jitted
-  ``StreamEngine`` step (``obs.metrics``'s discipline: every update is a
-  few reductions over values the step already materializes, fused into
-  the same XLA program, **zero extra host syncs** — drained only at
-  ``snapshot``). It counts integer per-(stream, tier) transactions:
-  writes, deletes, and ``resident_steps`` (the storage integral —
-  post-step occupancy × docs ingested, a doc-step rental meter that at
+* The bill (``realized_costs``) — the ``FleetMeter``'s int64
+  per-(stream, tier) counts priced on host in f64 at drain time: writes
+  at ``cw``, the final top-K read at ``cr``, the doc-step rental
+  integral (``doc_steps``: settled occupancy × docs ingested, which at
   chunk width 1 equals the simulator's per-doc doc-month accounting
-  exactly). Counts stay i32 on device (x64 is off on the hot path);
-  pricing happens on host in f64 at drain time, so identical integers
-  priced through identical dot products give bit-equal cost components.
-  Drain and rebase before a window approaches 2^31 doc-steps.
+  exactly) at the per-step storage rate, and every cascade or re-plan
+  hop at ``cr_src + cw_dst``. The meter's counts come from the step's
+  device fold (``metering.fold``), so no cost state rides the step, and
+  identical integers priced through identical dot products give
+  bit-equal cost components.
 
 * Closed-form **expected-cost trajectory** — the prefix integral of the
   write law (``chunk_law_np`` split across tier widths) plus the
@@ -43,98 +41,21 @@ Three pieces:
   false-positive rate stays ≤ alpha (property-tested). Alerts can union
   into the re-plan trigger exactly like ``residual_trigger``.
 
-Device-ledger scope: tiers are attributed by each doc's *static*
-position tier against the stream's current boundary vector (the leaf is
-updated by the host after a re-plan — no recompiles), with the same
-int32 attribution as the meter's device fold (``topk.tiers``).
-Migration-cascade streams lift residents above the static tier; their
-hop accounting is the ``FleetMeter``'s (``mig_reads``/``mig_writes``,
-counted by the meter fold in the step), and the reconciliation
-guarantees below are stated for non-cascade streams.
+Scope: the meter bills every document where it lives. Writes are
+attributed to the static tier against the stream's current boundary
+vector; on migration-cascade streams deletes, the final read and the
+storage integral follow the cascade floor, settled before the crossing
+step's rent accrues (at W = 1 that is within K doc-steps of the
+simulator per boundary crossed, the same total).
 """
 from __future__ import annotations
 
 import math
-from typing import List, NamedTuple, Tuple
+from typing import List, Tuple
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.core import topk
-
 from .residuals import chunk_law_np
-
-
-# ---------------------------------------------------------------------------
-# device ledger
-# ---------------------------------------------------------------------------
-
-class CostState(NamedTuple):
-    """Per-bucket device cost ledger (rows = the bucket's streams, padded
-    to the shard multiple; pad rows carry INT32_MAX bounds and never
-    count).
-
-    ``bounds`` holds the boundary vector as ``topk.quantize_boundaries``
-    gives it: doc ids are integers, so ``id >= ceil(b)`` ⟺ ``id >= b``,
-    and the int32 compare is exact at every position — the device tier
-    attribution is bit-equal to the host meter's f64 comparison."""
-
-    bounds: jax.Array  # (Mb, B) i32 — ceiled boundaries, INT32_MAX padded
-    writes: jax.Array  # (Mb, T) i32 — admits priced cw at the write tier
-    deletes: jax.Array  # (Mb, T) i32 — evictions per (current static) tier
-    resident_steps: jax.Array  # (Mb, T) i32 — Σ occupancy × chunk docs
-
-
-def init_bucket(pad_m: int, boundaries: np.ndarray,
-                n_tiers: int) -> CostState:
-    """Fresh ledger for one bucket: ``boundaries`` is the meter's
-    (m_true, B) f64 block for the bucket's rows; rows past it are
-    shard padding (INT32_MAX bounds — inert)."""
-    b = np.asarray(boundaries, np.float64)
-    bounds = np.full((pad_m, b.shape[1]), topk.INT32_MAX, np.int32)
-    bounds[: b.shape[0]] = topk.quantize_boundaries(b)
-    return CostState(
-        bounds=jnp.asarray(bounds),
-        writes=jnp.zeros((pad_m, n_tiers), jnp.int32),
-        deletes=jnp.zeros((pad_m, n_tiers), jnp.int32),
-        resident_steps=jnp.zeros((pad_m, n_tiers), jnp.int32))
-
-
-@jax.named_scope("obs")
-def accumulate_exact(cs: CostState, batch_ids, wrote, evicted_ids,
-                     state_ids) -> CostState:
-    """Fold one exact-backend bucket step into the ledger (pure; traced
-    inside the jitted step). Occupancy is recomputed from the post-step
-    reservoir ids, so ``resident_steps`` accrues occupancy × the chunk's
-    live docs — the right-Riemann storage integral, exact vs the
-    simulator's per-doc rental at chunk width 1."""
-    t = cs.writes.shape[1]
-    live = batch_ids >= 0
-    dw = topk.tier_counts(topk.tiers(batch_ids, cs.bounds), wrote & live, t)
-    dd = topk.tier_counts(topk.tiers(evicted_ids, cs.bounds),
-                          evicted_ids >= 0, t)
-    occ = topk.tier_counts(topk.tiers(state_ids, cs.bounds), state_ids >= 0,
-                           t)
-    docs = live.sum(axis=1, dtype=jnp.int32)
-    return cs._replace(writes=cs.writes + dw, deletes=cs.deletes + dd,
-                       resident_steps=cs.resident_steps
-                       + occ * docs[:, None])
-
-
-@jax.named_scope("obs")
-def accumulate_logmem(cs: CostState, batch_ids, wrote) -> CostState:
-    """Logmem-bucket step: no ids stored and nothing deletes, so
-    occupancy ≡ cumulative writes per tier and the storage integral
-    accrues the post-step cumulative write counts."""
-    t = cs.writes.shape[1]
-    live = batch_ids >= 0
-    dw = topk.tier_counts(topk.tiers(batch_ids, cs.bounds), wrote & live, t)
-    writes = cs.writes + dw
-    docs = live.sum(axis=1, dtype=jnp.int32)
-    return cs._replace(writes=writes,
-                       resident_steps=cs.resident_steps
-                       + writes * docs[:, None])
 
 
 # ---------------------------------------------------------------------------
@@ -175,39 +96,26 @@ def stream_pricing(engine) -> dict:
             "has_model": has_model}
 
 
-def device_counts(engine) -> dict:
-    """Drain the per-bucket device ledgers into global (M, T) int64
-    arrays (the only sync point — one transfer per leaf per bucket;
-    shard padding sliced back off)."""
-    t, m = engine.meter.n_tiers, engine.m
-    out = {name: np.zeros((m, t), np.int64)
-           for name in ("writes", "deletes", "resident_steps")}
-    for bi, b in enumerate(engine.buckets):
-        cs = engine._cost_states[bi]
-        rows = engine._global_rows[bi]
-        for name in out:
-            out[name][rows] = np.asarray(getattr(cs, name))[: b.m]
-    return out
-
-
 def realized_costs(engine) -> dict:
-    """Price the device ledger + the meter's host-side hop counters into
-    per-stream realized cost components (the ``SimResult`` convention:
-    writes @ cw, final reads @ cr × reads_per_window, doc-steps × the
-    per-step rental rate, migration/relocation hops priced
-    ``cr_src + cw_dst``)."""
+    """Price the meter's counters into per-stream realized cost
+    components (the ``SimResult`` convention: writes @ cw, final reads @
+    cr × reads_per_window, doc-steps × the per-step rental rate,
+    migration/relocation hops priced ``cr_src + cw_dst``). ``device``
+    holds the meter's (M, T) int64 write, delete and doc-step counts
+    that the bill prices (``resident_steps`` = ``doc_steps``)."""
     p = engine._pricing
-    dev = device_counts(engine)
     meter = engine.meter
-    writes = (dev["writes"] * p["cw"]).sum(1)
+    writes = (meter.writes * p["cw"]).sum(1)
     reads = (meter.reads * p["cr"]).sum(1) * p["reads_per_window"]
-    storage = (dev["resident_steps"] * p["step_rate"]).sum(1)
+    storage = (meter.doc_steps * p["step_rate"]).sum(1)
     migration = ((meter.mig_reads + meter.reloc_reads) * p["cr"]).sum(1) \
         + ((meter.mig_writes + meter.reloc_writes) * p["cw"]).sum(1)
     return {"writes": writes, "reads": reads, "storage": storage,
             "migration": migration,
             "total": writes + reads + storage + migration,
-            "device": dev}
+            "device": {"writes": meter.writes.copy(),
+                       "deletes": meter.deletes.copy(),
+                       "resident_steps": meter.doc_steps.copy()}}
 
 
 def cost_summary(engine) -> dict:
@@ -238,12 +146,14 @@ def cost_summary(engine) -> dict:
 
 def snapshot(engine) -> dict:
     """The engine's ``obs_snapshot`` cost section: fleet-level priced
-    components, the regret meter, the raw device counter totals, and the
-    alert channel state. Deterministic scalars only — bit-identical
-    sharded vs unsharded (integer device counts are row-independent)."""
+    components, the regret meter, the totals of the meter counts the
+    bill prices (the ``device`` section: the step's meter fold counts
+    them on the device), and the alert channel state. Deterministic
+    scalars only — bit-identical sharded vs unsharded (the integer
+    counts are row-independent)."""
     summ = cost_summary(engine)
     dev = summ["device"]
-    out = {
+    return {
         "realized": {k: float(summ[k].sum())
                      for k in ("writes", "reads", "storage", "migration",
                                "total")},
@@ -251,10 +161,8 @@ def snapshot(engine) -> dict:
         "regret": {"fleet": float(summ["regret"].sum()),
                    "max": float(summ["regret"].max()) if engine.m else 0.0},
         "device": {name: int(arr.sum()) for name, arr in dev.items()},
+        "alerts": engine._cost_monitor.snapshot(),
     }
-    if engine._cost_monitor is not None:
-        out["alerts"] = engine._cost_monitor.snapshot()
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -460,7 +368,7 @@ class CostMonitor:
         d = real_wcost - exp_wcost
         self.exp_writes_pt += np.where(active, mean, 0.0)[:, None] * wfrac
         # storage: realized doc-steps vs the survivor law's expectation
-        # at the chunk end (right-Riemann — the device's own accrual)
+        # at the chunk end (right-Riemann — the meter's own accrual)
         occ = np.where(self.logmem[:, None], self.exp_writes_pt,
                        expected_occupancy(self.bounds, self.k, b))
         plan_store = np.where(active,

@@ -47,7 +47,8 @@ _HELP = {
     "deletes": "tier delete transactions",
     "migrations": "documents cascaded across a boundary",
     "relocations": "residents re-tiered by online re-plans",
-    "resident_steps": "doc-step storage rental integral (obs.costs)",
+    "resident_steps": "doc-step storage rental integral the bill prices "
+                      "(the fleet meter's doc_steps)",
     "planned_total": "closed-form expected spend at the current position",
     "regret": "realized minus planned spend",
     "max_burn_ratio": "worst realized/planned spend over the long burn "

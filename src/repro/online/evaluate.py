@@ -229,8 +229,8 @@ def regret_table(engine: StreamEngine, traces=None, *,
                  drift_at: Optional[int] = None,
                  grid: int = 8) -> List[Dict]:
     """Per-tenant regret rows from a live engine's cost attribution
-    (requires ``ObsConfig(costs=True)``): realized spend from the device
-    ledger, the planner's closed-form expected spend, their difference
+    (requires ``ObsConfig(costs=True)``): realized spend priced from the
+    fleet meter, the planner's closed-form expected spend, their difference
     (regret vs plan), and — when ``traces`` and ``drift_at`` are given —
     regret vs the per-trace hindsight oracle (``hindsight_oracle``), the
     strongest baseline the paper admits. Cascade streams skip the oracle

@@ -5,7 +5,7 @@ uninterrupted run bit-for-bit or say exactly why it cannot:
 
 * ``snapshot`` / ``checkpoint`` — versioned, checksummed, sharding-
   portable snapshot/restore of the full engine state (device reservoirs,
-  drift evidence, metric and cost ledgers, host monitors, the ingest
+  drift evidence, metric counters, the host meter and monitors, the ingest
   cursor, and the decision event logs), written at chunk boundaries so
   the npy I/O overlaps the next chunk's compute.
 * ``faults`` — deterministic seed-driven fault injection: transient

@@ -72,9 +72,10 @@ class FleetCheckpointer:
         engine's ``chunks_ingested`` cursor afterwards names the next
         chunk to (re)deliver."""
         self.manager.wait()
-        template, _ = snapshot_mod.fleet_snapshot(engine)
-        tree = self.manager.restore(template, step=step, verify=verify)
         manifest = self.manager.manifest(step)
+        template = snapshot_mod.restore_template(
+            engine, int(manifest.get("n_leaves", -1)))
+        tree = self.manager.restore(template, step=step, verify=verify)
         snapshot_mod.fleet_restore(engine, tree,
                                    manifest.get("extra", {}))
         return int(manifest.get("generation", 0))

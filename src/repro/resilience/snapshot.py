@@ -5,10 +5,10 @@
 * the pytree holds every fixed-shape array — per-bucket reservoir /
   logmem states and drift evidence sliced to the TRUE row count (shard
   padding stripped, so a checkpoint written on one mesh restores onto
-  any other), device cost ledgers, the metrics counters collapsed to
-  their mesh-independent canonical form, and the host monitors' state
-  dicts (meter ledgers, residual and cost monitor evidence) — plus the
-  ingest cursor;
+  any other), the metrics counters collapsed to their mesh-independent
+  canonical form, and the host monitors' state dicts (meter ledgers —
+  which the cost bill prices — residual and cost monitor evidence) —
+  plus the ingest cursor;
 * ``meta`` is a JSON-able dict carrying everything variable-length or
   structural: the replan/admission event logs, tier-outage bookkeeping,
   and a fleet fingerprint that restore validates against.
@@ -68,10 +68,6 @@ def fleet_snapshot(engine) -> Tuple[Dict, Dict]:
         from repro.obs import metrics as metrics_mod
         counts, score = metrics_mod.to_canonical(engine._metrics_state)
         device["metrics"] = {"counts": counts, "score": score}
-    if engine._cost_states is not None:
-        device["costs"] = [_slice_rows(cs, b.m)
-                           for cs, b in zip(engine._cost_states,
-                                            engine.buckets)]
     host: Dict = {"meter": engine.meter.state_dict()}
     if engine._residuals is not None:
         host["residuals"] = engine._residuals.state_dict()
@@ -100,6 +96,28 @@ def fleet_snapshot(engine) -> Tuple[Dict, Dict]:
     return tree, meta
 
 
+# leaves per bucket of the device cost ledger that fleet steps carried
+# before the bill was priced from the meter (bounds, writes, deletes,
+# resident_steps under ``device["costs"]``)
+_RETIRED_COST_LEAVES = 4
+
+
+def restore_template(engine, n_leaves: int) -> Dict:
+    """The template a stored tree of ``n_leaves`` leaves restores
+    through: the engine's own snapshot, with a placeholder
+    ``device["costs"]`` entry when the checkpoint still holds the
+    retired per-bucket cost ledger. ``fleet_restore`` reads only the
+    entries it needs, so it skips that one: the meter's ledgers in
+    ``host["meter"]`` already hold the counts the bill prices."""
+    tree, _ = fleet_snapshot(engine)
+    retired = _RETIRED_COST_LEAVES * len(engine.buckets)
+    if n_leaves == len(jax.tree_util.tree_leaves(tree)) + retired:
+        tree["device"]["costs"] = [
+            (np.zeros(0, np.int32),) * _RETIRED_COST_LEAVES
+            for _ in engine.buckets]
+    return tree
+
+
 def _restore_bucket(engine, bi: int, restored, fresh):
     """Re-pad one bucket's restored rows to the engine's shard multiple
     (pad rows keep fresh-init values) and re-pin the fleet sharding."""
@@ -120,7 +138,9 @@ def _restore_bucket(engine, bi: int, restored, fresh):
 def fleet_restore(engine, tree: Dict, meta: Dict) -> None:
     """Load a snapshot into a freshly built engine (same specs and
     obs/replan configuration; ANY mesh size). Mutates the engine in
-    place; raises ``ValueError`` on a fleet-shape mismatch."""
+    place; raises ``ValueError`` on a fleet-shape mismatch. Entries of
+    ``tree`` the engine has no state for (a retired ``device["costs"]``)
+    are ignored."""
     fp = _fingerprint(engine)
     if meta.get("fleet") not in (None, fp):
         raise ValueError(
@@ -160,23 +180,9 @@ def fleet_restore(engine, tree: Dict, meta: Dict) -> None:
             from repro.parallel import fleet
             ms = fleet.shard_rows(engine.mesh, ms)
         engine._metrics_state = ms
-    if engine._cost_states is not None:
-        if "costs" not in device:
-            raise ValueError("checkpoint has no cost ledgers but the "
-                             "engine was built with obs costs on")
-        from repro.obs import costs as costs_mod
-        fresh = [jax.tree_util.tree_map(
-            np.asarray,
-            costs_mod.init_bucket(pm,
-                                  engine.meter.boundaries[rows],
-                                  engine.meter.n_tiers))
-            for pm, rows in zip(engine._pad_m, engine._global_rows)]
-        engine._cost_states = [
-            _restore_bucket(engine, bi, device["costs"][bi], fresh[bi])
-            for bi in range(len(engine.buckets))]
     engine.meter.load_state(tree["host"]["meter"])
-    # the meter fold's device rows (and the cost ledgers' boundaries) are
-    # copies of the host meter's: reload them from the restored ledgers
+    # the meter fold's device rows are copies of the host meter's:
+    # reload them from the restored ledgers
     engine._load_device_meter()
     if engine._residuals is not None:
         engine._residuals.load_state(tree["host"]["residuals"])

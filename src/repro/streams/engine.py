@@ -147,21 +147,13 @@ def placements(state: BatchedReservoirState, r) -> jax.Array:
     return jnp.where(state.ids >= 0, t, -1)
 
 
-def evicted_ids(old: BatchedReservoirState,
-                new: BatchedReservoirState) -> jax.Array:
-    """(M, K) local doc ids of ``old`` absent from ``new`` (-1 = none),
-    by id search (``topk.evicted``), for any pair of states."""
-    ev = jax.vmap(topk.evicted)(_as_single(old), _as_single(new))
-    return jnp.where(ev, old.ids, PAD_ID)
-
-
 @jax.named_scope("evicted")
 def dropped_ids(old: BatchedReservoirState,
                 new: BatchedReservoirState) -> jax.Array:
     """(M, K) local doc ids evicted by the step (-1 = none) — the storage
     the fleet can free (paper §VI). ``new`` is ``update``'s or
     ``filtered_update``'s result from ``old``, so the evictions are read
-    off the merge's order (``topk.dropped``); equal to ``evicted_ids``."""
+    off the merge's order (``topk.dropped``), with no id search."""
     return jnp.where(topk.dropped(_as_single(old), _as_single(new)),
                      old.ids, PAD_ID)
 
@@ -170,8 +162,7 @@ def dropped_ids(old: BatchedReservoirState,
 def _make_step(use_kernel_filter: bool, block_n: int, drift_cfg=None,
                bucket_ks: Tuple[int, ...] = (),
                with_metrics: bool = False, mesh=None, donate: bool = False,
-               bucket_engines: Tuple[str, ...] = (),
-               with_costs: bool = False):
+               bucket_engines: Tuple[str, ...] = ()):
     """One jitted step over ALL buckets: states/batches are same-length
     tuples (the pytree structure is static, so the whole fleet advances in
     a single XLA computation). With ``drift_cfg`` (online re-planning) the
@@ -202,19 +193,17 @@ def _make_step(use_kernel_filter: bool, block_n: int, drift_cfg=None,
     program; when off, ``mstate`` is an empty tuple and the traced
     computation is exactly the pre-obs step (bit-identical outputs).
 
-    With ``with_costs`` (obs.costs) the step also folds each bucket's
-    device ``CostState`` ledger — integer per-(stream, tier) write /
-    delete / doc-step counts against the stream's boundary vector,
-    priced on host only at drain. Same discipline as the metrics state:
-    fused reductions over values the step already materializes, and
-    ``cstates = ()`` when off leaves the traced computation unchanged.
-
     With ``meters`` (one ``metering.MeterState`` per bucket) the step
     also meters each bucket on the device (``metering.fold``): it returns
     the advanced meter states and each bucket's per-(stream, tier)
     ``MeterDelta`` for ``FleetMeter.record_update``, so no per-document
     array leaves the device. ``meters = ()`` skips the fold (both
-    outputs are then empty tuples).
+    outputs are then empty tuples). The cost bill (``obs.costs``) is
+    priced from those meter counts on the host; nothing of it rides the
+    step.
+
+    The step is ``step(states, batches, dstates, mstate, meters)`` and
+    returns ``(states, dstates, mstate, meters, deltas)``.
 
     With ``mesh`` (a ``parallel.fleet`` mesh) the whole step is
     ``shard_map``-ped over the fleet axis: every leading-M leaf —
@@ -235,15 +224,13 @@ def _make_step(use_kernel_filter: bool, block_n: int, drift_cfg=None,
         from repro.online import drift as drift_mod
     if with_metrics:
         from repro.obs import metrics as metrics_mod
-    if with_costs:
-        from repro.obs import costs as costs_mod
 
-    def step(states, batches, dstates, mstate, cstates, meters):
+    def step(states, batches, dstates, mstate, meters):
         if with_metrics and mesh is not None:
             # inside shard_map: squeeze this shard's (1, 8) counter
             # block to the flat layout the accumulate laws expect
             mstate = metrics_mod.shard_local(mstate)
-        new_states, new_dstates, new_cstates = [], [], []
+        new_states, new_dstates = [], []
         new_meters, deltas = [], []
         for bi, (st, (s, i)) in enumerate(zip(states, batches)):
             # quarantine non-finite scores before any compare sees them:
@@ -269,9 +256,6 @@ def _make_step(use_kernel_filter: bool, block_n: int, drift_cfg=None,
                 bar = st.tau
                 slack = logmem.law_slack(bucket_ks[bi])
                 stored = ()
-                if with_costs:
-                    new_cstates.append(costs_mod.accumulate_logmem(
-                        cstates[bi], i, wrote))
             else:
                 new, wrote = filtered_update(st, s, i, block_n=block_n,
                                              use_pallas=use_kernel_filter)
@@ -279,9 +263,6 @@ def _make_step(use_kernel_filter: bool, block_n: int, drift_cfg=None,
                 bar = st.scores[:, -1]
                 slack = 0.0
                 stored = (ev, new.ids)
-                if with_costs:
-                    new_cstates.append(costs_mod.accumulate_exact(
-                        cstates[bi], i, wrote, ev, new.ids))
             new_states.append(new)
             if meters:
                 ms, delta = metering.fold(meters[bi], i, wrote, *stored)
@@ -312,15 +293,15 @@ def _make_step(use_kernel_filter: bool, block_n: int, drift_cfg=None,
         if with_metrics and mesh is not None:
             mstate = metrics_mod.shard_pack(mstate)
         return tuple(new_states), tuple(new_dstates), mstate, \
-            tuple(new_cstates), tuple(new_meters), tuple(deltas)
+            tuple(new_meters), tuple(deltas)
 
     if mesh is not None:
         from repro.core import jaxcompat
         from repro.parallel import fleet
         spec = fleet.row_spec()
-        step = jaxcompat.shard_map(step, mesh=mesh, in_specs=(spec,) * 6,
-                                   out_specs=(spec,) * 6)
-    return jax.jit(step, donate_argnums=(0, 2, 3, 4) if donate else ())
+        step = jaxcompat.shard_map(step, mesh=mesh, in_specs=(spec,) * 5,
+                                   out_specs=(spec,) * 5)
+    return jax.jit(step, donate_argnums=(0, 2, 3) if donate else ())
 
 
 # ---------------------------------------------------------------------------
@@ -563,23 +544,13 @@ class StreamEngine:
                     self.meter.ks, alpha=obs.config.residual_alpha,
                     max_checks=obs.config.residual_max_checks,
                     law_slack=slack_rows)
-        # live cost attribution (obs.costs): device CostState ledger in
-        # the step, host CostMonitor (cost residuals + budget burn rate)
-        # off the meter drain
-        self._cost_states = None
+        # live cost attribution (obs.costs): the bill is priced from the
+        # meter's counts, and the host CostMonitor (cost residuals +
+        # budget burn rate) runs off the meter drain
         self._cost_monitor = None
         self._pricing = None
         if obs is not None and obs.config.costs:
             from repro.obs import costs as costs_mod
-            self._cost_states = [
-                costs_mod.init_bucket(pm,
-                                      self.meter.boundaries[rows],
-                                      self.meter.n_tiers)
-                for pm, rows in zip(self._pad_m, self._global_rows)]
-            if mesh is not None:
-                from repro.parallel import fleet
-                self._cost_states = [fleet.shard_rows(mesh, cs)
-                                     for cs in self._cost_states]
             self._pricing = costs_mod.stream_pricing(self)
             slack_rows = np.where(
                 self.meter.logmem,
@@ -603,8 +574,7 @@ class StreamEngine:
             bucket_ks=tuple(b.k for b in self.buckets),
             with_metrics=self._metrics_state is not None,
             mesh=mesh, donate=donate,
-            bucket_engines=tuple(b.engine for b in self.buckets),
-            with_costs=self._cost_states is not None)
+            bucket_engines=tuple(b.engine for b in self.buckets))
         self._step = self._step_factory(False)
         self._donating_step = None  # built lazily by ingest_chunks
         # every dispatch is counted per (bucket widths, donate, exact
@@ -702,19 +672,13 @@ class StreamEngine:
     def _load_device_meter(self, buckets: Optional[Sequence[int]] = None
                            ) -> None:
         """(Re)load the device copies of the host meter's rows — the
-        state the step's meter fold reads, and the cost ledger's
-        boundaries — after the host changed them (a re-plan, an
-        evacuation, a restore). Shapes are unchanged, so nothing
-        recompiles."""
+        state the step's meter fold reads — after the host changed them
+        (a re-plan, an evacuation, a restore). Shapes are unchanged, so
+        nothing recompiles."""
         for bi in (range(len(self.buckets)) if buckets is None
                    else buckets):
-            host = self.meter.device_state(self._global_rows[bi],
-                                           self._pad_m[bi])
-            self._meter_states[bi] = self._place(host)
-            if self._cost_states is not None:
-                # a copy of its own: the step donates the cost ledger
-                self._cost_states[bi] = self._cost_states[bi]._replace(
-                    bounds=self._place(host.bounds))
+            self._meter_states[bi] = self._place(self.meter.device_state(
+                self._global_rows[bi], self._pad_m[bi]))
 
     def _dispatch(self, batches, donate: bool, meter: bool = True):
         """Run one (already staged) fleet step and swap in the new
@@ -724,8 +688,6 @@ class StreamEngine:
                    if self._drift_states is not None else ())
         mstate = (self._metrics_state
                   if self._metrics_state is not None else ())
-        cstates = (tuple(self._cost_states)
-                   if self._cost_states is not None else ())
         if donate:
             if self._donating_step is None:
                 self._donating_step = self._step_factory(True)
@@ -739,17 +701,15 @@ class StreamEngine:
                         if b.engine == "exact")
         key = (widths, donate, members)
         with self._span("ingest.dispatch"):
-            (new_states, new_dstates, mstate, new_cstates, new_meters,
-             deltas) = self._step_probe.track(
-                step, tuple(self._states), batches, dstates, mstate,
-                cstates, tuple(self._meter_states), key=key)
+            new_states, new_dstates, mstate, new_meters, deltas = \
+                self._step_probe.track(
+                    step, tuple(self._states), batches, dstates, mstate,
+                    tuple(self._meter_states), key=key)
         self._states = list(new_states)
         if self._metrics_state is not None:
             self._metrics_state = mstate
         if self._drift_states is not None:
             self._drift_states = list(new_dstates)
-        if self._cost_states is not None:
-            self._cost_states = list(new_cstates)
         if meter:
             self._meter_states = list(new_meters)
         return deltas, new_states
@@ -1367,7 +1327,7 @@ class StreamEngine:
         }
         if self._residuals is not None:
             out["residuals"]["alerts"] = self._residuals.snapshot()
-        if self._cost_states is not None:
+        if self._cost_monitor is not None:
             from repro.obs import costs as costs_mod
             out["costs"] = costs_mod.snapshot(self)
         out["resilience"] = {
@@ -1383,8 +1343,8 @@ class StreamEngine:
 
     def cost_summary(self) -> Dict:
         """Per-stream realized / planned / regret cost arrays from the
-        device ledger + host monitor (``obs.costs.cost_summary``)."""
-        if self._cost_states is None:
+        meter's counts + host monitor (``obs.costs.cost_summary``)."""
+        if self._cost_monitor is None:
             raise ValueError("engine built without obs= (or costs off)")
         from repro.obs import costs as costs_mod
         return costs_mod.cost_summary(self)

@@ -8,7 +8,12 @@ filtered form ``lax.top_k`` with a ``take_along_axis`` of the ids and a
 scatter of the survivors' mask back to batch positions. The filtered form
 cuts ties by batch position and admits only scores strictly above the
 bar, so it equals ``update`` only when each stream's ids arrive in
-increasing order."""
+increasing order.
+
+Also the id-search form of eviction (``evicted``, ``evicted_ids``), the
+plain reference for ``core.topk.dropped`` and
+``streams.engine.dropped_ids``, which read evictions off the merge's
+order."""
 from __future__ import annotations
 
 import functools
@@ -74,3 +79,17 @@ def filtered_update(state, batch_scores, batch_ids, use_pallas=False):
     return engine.BatchedReservoirState(
         new.scores, new.ids, _seen(state, batch_ids)), \
         wrote & (batch_ids >= 0)
+
+
+def evicted(old, new):
+    """One stream: mask over ``old.ids`` of entries no longer present in
+    ``new``, by searching ``new.ids`` for every old id (``member``), for
+    any pair of states."""
+    return (old.ids >= 0) & ~topk.member(old.ids, new.ids)
+
+
+def evicted_ids(old, new):
+    """The fleet: (M, K) local doc ids of ``old`` absent from ``new``
+    (-1 = none), by ``evicted``."""
+    ev = jax.vmap(evicted)(engine._as_single(old), engine._as_single(new))
+    return jnp.where(ev, old.ids, engine.PAD_ID)

@@ -2,15 +2,16 @@
 
 The contract under test, layer by layer:
 
-* carrying the device ``CostState`` ledger through the jitted step must
-  not perturb the computation (bit-identity with costs off);
-* the ledger's integer (stream, tier) counts must reconcile bit-exactly
-  with the host meter, and — at W=1, where the engine's chunk timing
-  equals the simulator's per-doc timing — with the trace-driven
-  simulator's priced write/read components (storage to fp tolerance:
-  same integer doc-steps, host-priced in one f64 dot product each side);
-* the sharded ledger must drain to the same global counts as the
-  single-device run (row-independent accumulation);
+* pricing the bill must not perturb the computation (bit-identity with
+  costs off);
+* the bill priced from the meter's integer (stream, tier) counts must
+  reconcile — at W=1, where the engine's chunk timing equals the
+  simulator's per-doc timing — with the trace-driven simulator's priced
+  write/read components bit-exactly (storage to fp tolerance: same
+  integer doc-steps, host-priced in one f64 dot product each side), on
+  static and on migration-cascade streams;
+* the sharded fleet must bill the same as the single-device run
+  (row-independent accumulation);
 * the ``CostMonitor`` cost-residual / budget burn-rate channels hold
   their false-positive budget on null (undrifted) fleets, and catch a
   genuine overspend (drift into an expensive tier) fast enough to drive
@@ -33,18 +34,24 @@ needs_mesh = pytest.mark.skipif(
            "--xla_force_host_platform_device_count=8)")
 
 
-def _w1_fleet(n=512, k=8, m=3, seed=0, engines=None):
-    """Per-doc (W=1) ingest: engine chunk timing == simulator timing."""
+def _w1_fleet(n=512, k=8, m=3, seed=0, engines=None, cascade_at=None):
+    """Per-doc (W=1) ingest: engine chunk timing == simulator timing.
+    ``cascade_at`` places every stream explicitly, one boundary there
+    with the migration cascade on (priced by the same cost model)."""
     cm = cc.hbm_host_preset(n_docs=n, k=k, doc_gb=1e-4, window_seconds=60.0)
     rng = np.random.default_rng(seed)
     traces = [simulator.random_rank_trace(n, rng) for _ in range(m)]
+    place = ({} if cascade_at is None
+             else {"boundaries": (float(cascade_at),), "migrate": True})
     specs = [StreamSpec(stream_id=i, k=k, cost_model=cm,
-                        engine=engines[i] if engines else "exact")
+                        engine=engines[i] if engines else "exact", **place)
              for i in range(m)]
     return cm, traces, specs
 
 
-def _run_w1(traces, specs, mesh=None):
+def _run_w1(traces, specs, mesh=None, trail=None):
+    """Ingest one doc per stream per step; ``trail`` (a list) collects
+    the meter's cumulative (M, T) writes after every step."""
     m, n = len(traces), len(traces[0])
     obs = Observability(ObsConfig(costs=True))
     eng = StreamEngine(specs, obs=obs, mesh=mesh)
@@ -52,6 +59,8 @@ def _run_w1(traces, specs, mesh=None):
         eng.ingest(np.arange(m),
                    np.array([t[pos] for t in traces], np.float32),
                    np.full(m, pos, np.int64))
+        if trail is not None:
+            trail.append(eng.meter.writes.copy())
     eng.finalize()
     return eng
 
@@ -61,9 +70,8 @@ def _run_w1(traces, specs, mesh=None):
 # ---------------------------------------------------------------------------
 
 def test_costs_off_and_on_bit_identical_output():
-    """Folding the CostState into the step must not change survivors,
-    reservoir state, or the meter ledger — the cost accumulators only
-    read values the step already materializes."""
+    """Pricing the bill must not change survivors, reservoir state, or
+    the meter ledger — the bill only reads the meter's counts."""
     rng = np.random.default_rng(11)
     n, m, k = 2048, 5, 16
     traces = rng.standard_normal((m, n)).astype(np.float32)
@@ -93,59 +101,93 @@ def test_costs_off_and_on_bit_identical_output():
 
 
 # ---------------------------------------------------------------------------
-# ledger reconciliation: device == meter == simulator
+# bill reconciliation: meter-priced bill == simulator
 # ---------------------------------------------------------------------------
 
-def test_cost_ledger_reconciles_with_simulator_at_w1():
-    """Exact engine, one doc per ingest: the device ledger's integer
-    counts equal the meter's, and the host-priced realized costs equal
-    the trace-driven simulator's bill — writes and reads bit-exactly
-    (identical integers through identical f64 dot products), storage to
-    fp tolerance of the identical integer doc-step rental."""
+@pytest.mark.parametrize("cascade_at", [None, 200],
+                         ids=["static", "cascade"])
+def test_cost_ledger_reconciles_with_simulator_at_w1(cascade_at):
+    """Exact engine, one doc per ingest: the bill priced from the meter's
+    counts equals the trace-driven simulator's — writes and reads
+    bit-exactly on static streams (identical integers through identical
+    f64 dot products), storage to 1e-9 of the identical integer doc-step
+    rental. On cascade streams the write, read and hop counts are equal
+    and their prices agree to 1e-12, and the residents are billed where
+    they live: doc-steps equal the simulator's in total, and per tier to
+    within K per boundary crossed (the meter settles the hop before the
+    crossing step's rent accrues, the simulator after)."""
     n, k = 512, 8
-    cm, traces, specs = _w1_fleet(n=n, k=k, m=3, seed=0)
+    cm, traces, specs = _w1_fleet(n=n, k=k, m=3, seed=0,
+                                  cascade_at=cascade_at)
     eng = _run_w1(traces, specs)
     summ = eng.cost_summary()
     dev = summ["device"]
-    np.testing.assert_array_equal(dev["writes"], eng.meter.writes)
-    np.testing.assert_array_equal(dev["deletes"], eng.meter.deletes)
-    np.testing.assert_array_equal(dev["resident_steps"],
-                                  eng.meter.doc_steps)
     nt = cm if isinstance(cm, cc.NTierCostModel) else cm.as_ntier()
     slot = nt.workload.window_months / n
     depth = int(np.isfinite(eng.meter.boundaries[0]).sum())
     for i, t in enumerate(traces):
         res = evaluate.realized(t, k, cm,
-                                tuple(eng.meter.boundaries[i][:depth]))
+                                tuple(eng.meter.boundaries[i][:depth]),
+                                migrate=cascade_at is not None)
         np.testing.assert_array_equal(res.writes_per_tier,
                                       eng.meter.writes[i])
+        np.testing.assert_array_equal(res.writes_per_tier, dev["writes"][i])
+        np.testing.assert_array_equal(res.reads_per_tier, eng.meter.reads[i])
+        assert res.migrated == eng.meter.migrations[i]
         dm = np.rint(res.doc_months_per_tier / slot).astype(np.int64)
-        np.testing.assert_array_equal(dm, dev["resident_steps"][i])
-        assert res.cost_writes == summ["writes"][i]
-        assert res.cost_reads == summ["reads"][i]
-        assert np.isclose(res.cost_storage, summ["storage"][i], rtol=1e-9)
-        assert np.isclose(res.cost_total, summ["total"][i], rtol=1e-9)
+        steps = dev["resident_steps"][i]
+        assert dm.sum() == steps.sum()
+        if cascade_at is None:
+            assert res.cost_writes == summ["writes"][i]
+            assert res.cost_reads == summ["reads"][i]
+            assert res.cost_migration == summ["migration"][i] == 0.0
+            np.testing.assert_array_equal(dm, steps)
+            assert np.isclose(res.cost_storage, summ["storage"][i],
+                              rtol=1e-9)
+            assert np.isclose(res.cost_total, summ["total"][i], rtol=1e-9)
+        else:
+            # the counts under them are equal (above); the simulator's
+            # dot product may round a two-term sum differently
+            for name in ("writes", "reads", "migration"):
+                assert np.isclose(getattr(res, f"cost_{name}"),
+                                  summ[name][i], rtol=1e-12, atol=0.0)
+            crossed = int(eng.meter.floor[i])
+            assert crossed == depth and res.migrated > 0
+            assert np.abs(dm - steps).max() <= k * crossed
+            # so the storage bill is off by at most K doc-steps per
+            # boundary priced at the spread of the tiers' step rates
+            rate = eng._pricing["step_rate"][i]
+            assert np.isclose(rate @ dm, res.cost_storage, rtol=1e-9)
+            assert abs(summ["storage"][i] - res.cost_storage) \
+                <= k * crossed * np.ptp(rate) * (1 + 1e-9)
 
 
 def test_logmem_ledger_reconciles_with_meter_at_w1():
-    """Logmem rows store no ids, so the ledger counts cumulative writes
-    as occupancy — exactly the meter's convention; device must equal
-    meter on writes, zero deletes, and the doc-step rental integral."""
+    """Logmem rows store no ids, so occupancy is cumulative writes: the
+    storage bill is the rental of the cumulative writes after every
+    ingested doc, priced at the per-step rate; the write bill prices the
+    meter's writes, and nothing deletes."""
     cm, traces, specs = _w1_fleet(n=512, k=16, m=4, seed=2,
                                   engines=["logmem"] * 4)
-    eng = _run_w1(traces, specs)
-    dev = costs_mod.device_counts(eng)
-    np.testing.assert_array_equal(dev["writes"], eng.meter.writes)
-    assert int(dev["deletes"].sum()) == 0
-    np.testing.assert_array_equal(dev["resident_steps"],
-                                  eng.meter.doc_steps)
+    trail = []
+    eng = _run_w1(traces, specs, trail=trail)
+    summ = eng.cost_summary()
+    p = eng._pricing
+    assert int(summ["device"]["deletes"].sum()) == 0
+    assert summ["device"]["writes"].sum() > 0
+    rental = np.sum(trail, axis=0)
+    np.testing.assert_array_equal(summ["device"]["resident_steps"], rental)
+    np.testing.assert_array_equal(summ["writes"],
+                                  (trail[-1] * p["cw"]).sum(1))
+    np.testing.assert_array_equal(summ["storage"],
+                                  (rental * p["step_rate"]).sum(1))
 
 
 @needs_mesh
 def test_sharded_cost_ledger_matches_unsharded():
-    """The per-row CostState shards with the fleet axis; draining the
-    sharded ledger must give the same global counts, and the same
-    priced snapshot, as the single-device run — on a mixed exact/logmem
+    """The meter's rows shard with the fleet axis; the sharded fleet must
+    bill the same per-stream components, and give the same priced
+    snapshot, as the single-device run — on a mixed exact/logmem
     fleet."""
     from repro.parallel import fleet
     n, k, m = 512, 16, 8
@@ -156,11 +198,11 @@ def test_sharded_cost_ledger_matches_unsharded():
     e1 = _run_w1(traces, specs)
     e2 = _run_w1(traces, specs,
                  mesh=fleet.fleet_mesh(min(jax.local_device_count(), 8)))
-    d1, d2 = costs_mod.device_counts(e1), costs_mod.device_counts(e2)
-    for name in d1:
-        np.testing.assert_array_equal(d1[name], d2[name])
-    np.testing.assert_array_equal(d1["writes"], e1.meter.writes)
-    np.testing.assert_array_equal(d1["resident_steps"], e1.meter.doc_steps)
+    s1, s2 = e1.cost_summary(), e2.cost_summary()
+    for name in ("writes", "reads", "storage", "migration", "total"):
+        np.testing.assert_array_equal(s1[name], s2[name])
+    for name in s1["device"]:
+        np.testing.assert_array_equal(s1["device"][name], s2["device"][name])
     assert e1.obs_snapshot()["costs"] == e2.obs_snapshot()["costs"]
 
 

@@ -130,7 +130,7 @@ def test_checkpoint_kill_restore_resume_bitwise(tmp_path, kill_at):
 
 
 def test_checkpoint_full_obs_replan_roundtrip(tmp_path):
-    """Full-fat engine (metrics + residual monitor + cost ledgers +
+    """Full-fat engine (metrics + residual monitor + cost monitor +
     drift/replan state): restore mid-run and resume — replan events,
     cost attribution, and the obs snapshot all land bitwise."""
     from repro.core import costs as core_costs
@@ -182,6 +182,42 @@ def test_checkpoint_full_obs_replan_roundtrip(tmp_path):
     oa, ob = ref.obs_snapshot(), eng2.obs_snapshot()
     assert oa["engine"] == ob["engine"]
     assert oa["meter"] == ob["meter"]
+
+
+def test_checkpoint_with_retired_cost_ledger_restores(tmp_path):
+    """A checkpoint that still holds the per-bucket device cost ledger
+    (``device["costs"]``: bounds, writes, deletes, resident_steps, int32,
+    as fleet steps carried it before the bill was priced from the meter)
+    restores onto a costs-on engine: the entry is skipped, and the
+    resumed run bills bitwise like the uninterrupted one."""
+    from repro.checkpoint.manager import CheckpointManager
+
+    def build():
+        return StreamEngine(_specs(),
+                            obs=Observability(ObsConfig(costs=True)))
+
+    ref = build()
+    make_chunk = _chunk_maker(ref)
+    for i in range(8):
+        ref.ingest_dense(make_chunk(i))
+    eng = build()
+    for i in range(5):
+        eng.ingest_dense(make_chunk(i))
+    tree, meta = fleet_snapshot(eng)
+    t = eng.meter.n_tiers
+    tree["device"]["costs"] = [
+        (np.zeros((b.m, t - 1), np.int32),)
+        + tuple(np.ones((b.m, t), np.int32) for _ in range(3))
+        for b in eng.buckets]
+    CheckpointManager(str(tmp_path)).save(tree, step=5, blocking=True,
+                                          extra=meta)
+    eng2 = build()
+    FleetCheckpointer(str(tmp_path)).restore(eng2)
+    assert eng2.chunks_ingested == 5
+    for i in range(5, 8):
+        eng2.ingest_dense(make_chunk(i))
+    _assert_same_finals(ref, eng2)
+    assert ref.obs_snapshot()["costs"] == eng2.obs_snapshot()["costs"]
 
 
 def test_restore_rejects_mismatched_fleet(tmp_path):

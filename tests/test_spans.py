@@ -216,7 +216,7 @@ def test_lowered_step_carries_named_scopes():
     batches = eng._stage_batches(_dense(eng, 0))
     text = eng._step.lower(
         tuple(eng._states), batches, tuple(eng._drift_states),
-        eng._metrics_state, (), tuple(eng._meter_states)
+        eng._metrics_state, tuple(eng._meter_states)
     ).compile().as_text()
     # a scope entered under vmap reads "vmap(<scope>)" in the name stack
     stacks = [[re.sub(r"^vmap\((.+)\)$", r"\1", part)

@@ -60,7 +60,8 @@ def test_signed_zero_scores_tie_by_id():
     assert np.asarray(new.scores).tolist() == [1.0, 0.0]
     assert np.asarray(wrote).tolist() == [True]
     assert (np.asarray(topk.dropped(state, new)).tolist()
-            == np.asarray(topk.evicted(state, new)).tolist() == [False, True])
+            == np.asarray(merge_reference.evicted(state, new)).tolist()
+            == [False, True])
 
 
 def _random_state(rng, k, lo, hi):
@@ -237,7 +238,8 @@ def _eviction_batch(case, rng, state, step, m, w):
 @pytest.mark.parametrize("case", MERGE_CASES)
 def test_step_evictions_by_rank_equal_id_search(path, case):
     """The step's evictions (``dropped_ids``, read off the merge's order)
-    equal the id search ``evicted_ids`` after each update path."""
+    equal the id search ``merge_reference.evicted_ids`` after each update
+    path."""
     rng = np.random.default_rng(11)
     m, k = 4, 8
     w = 2 if case == "unfull" else 16  # unfull: 3 chunks never fill K
@@ -251,7 +253,7 @@ def test_step_evictions_by_rank_equal_id_search(path, case):
     for step in range(3):
         sc, ids = _eviction_batch(case, rng, st, step, m, w)
         new, _ = fns[path](st, sc, ids)
-        want = np.asarray(engine.evicted_ids(st, new))
+        want = np.asarray(merge_reference.evicted_ids(st, new))
         np.testing.assert_array_equal(np.asarray(engine.dropped_ids(st, new)),
                                       want)
         evicted += int((want >= 0).sum())

@@ -10,6 +10,8 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.core import topk
 
+import merge_reference
+
 
 def oracle_topk(scores: np.ndarray, k: int):
     """Exact top-k with earlier-index tie-break."""
@@ -159,7 +161,7 @@ def _eviction_case(case, m=6, k=8, w=12, seed=7):
 def test_dropped_equals_evicted(case):
     old, scores, ids = _eviction_case(case)
     new, _ = jax.vmap(topk.update)(old, scores, ids)
-    want = np.asarray(jax.vmap(topk.evicted)(old, new))
+    want = np.asarray(jax.vmap(merge_reference.evicted)(old, new))
     np.testing.assert_array_equal(np.asarray(topk.dropped(old, new)), want)
     np.testing.assert_array_equal(
         np.asarray(jax.vmap(topk.dropped)(old, new)), want)

@@ -136,7 +136,7 @@ def test_fleet_step_compiles(one_chip, compiled_kernels, kernel):
             floor=_spec(one_chip, (m,), jnp.int32),
             migrate=_spec(one_chip, (m,), jnp.bool_),
             observed=_spec(one_chip, (m,), jnp.int32)) for m in (M, LM))
-    c = step.lower(states, batches, (), mstate, (), meters).compile()
+    c = step.lower(states, batches, (), mstate, meters).compile()
     assert (_kernels(c) > 0) == kernel
     mem = c.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes

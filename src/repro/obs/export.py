@@ -28,7 +28,7 @@ _COUNTER_LEAVES = frozenset({
     "checks", "steps", "hits", "misses", "compiles",
     "scores_quarantined", "chunks_ingested", "checkpoints_written",
     "redeliveries_dropped", "delivery_retries", "tier_outages",
-    "route_slots", "route_useful",
+    "route_slots", "route_useful", "route_resorted", "route_resorted_rows",
 })
 
 # HELP text per terminal path component (kept to the metrics whose
@@ -65,6 +65,9 @@ _HELP = {
     "tier_outages": "tier outage declarations (cumulative)",
     "route_slots": "dense slots the router emitted (documents + padding)",
     "route_useful": "routed slots holding a document",
+    "route_resorted": "routed batches in which some stream's doc ids did "
+                      "not ascend, so its row was sorted by doc id",
+    "route_resorted_rows": "stream rows the router sorted by doc id",
 }
 
 

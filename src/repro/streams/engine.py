@@ -609,8 +609,12 @@ class StreamEngine:
         """Feed a mixed batch of scored docs — (stream_id, score, local doc
         index) triples in arbitrary order — through one jitted fleet step.
 
-        A doc id may appear at most once per stream per batch (they are
-        stream positions); the router rejects within-batch duplicates.
+        The router groups the batch by stream in one stable radix pass,
+        which keeps each stream's documents in batch order; it sorts a
+        stream's documents by doc id only where they arrive out of
+        position order (``route_resorted`` counts those batches). A doc
+        id may appear at most once per stream per batch (they are stream
+        positions); the router rejects within-batch duplicates.
         Re-observations across batches are deduped by the merge itself."""
         if self._tracer is not None and self._obs.config.trace_ingest:
             with self._tracer.span("ingest", docs=int(len(stream_ids))):
@@ -621,10 +625,12 @@ class StreamEngine:
     def _ingest(self, stream_ids, scores, doc_ids, pad_to) -> None:
         r = self.router
         slots, useful = r.route_slots, r.route_useful
+        resorted = r.route_resorted_rows
         with self._span("ingest.route") as sp:
             routed = r.route(stream_ids, scores, doc_ids, pad_to=pad_to)
             sp.attrs["slots"] = r.route_slots - slots
             sp.attrs["useful"] = r.route_useful - useful
+            sp.attrs["resorted"] = r.route_resorted_rows - resorted
         self._run_chunk(routed)
 
     def _span(self, name: str, chunk: Optional[int] = None, **attrs):
@@ -1291,7 +1297,10 @@ class StreamEngine:
                                "logmem_streams":
                                    int(self.meter.logmem.sum())},
                      "router": {"route_slots": self.router.route_slots,
-                                "route_useful": self.router.route_useful}}
+                                "route_useful": self.router.route_useful,
+                                "route_resorted": self.router.route_resorted,
+                                "route_resorted_rows":
+                                    self.router.route_resorted_rows}}
         if self._metrics_state is not None:
             from repro.obs import metrics as metrics_mod
             out["engine"] = metrics_mod.snapshot(self._metrics_state)

@@ -126,10 +126,29 @@ def test_router_counters_reach_the_snapshot_and_exposition():
     eng, _ = _engine()
     eng.ingest([0, 1, 1], [1.0, 2.0, 3.0], [0, 0, 1])
     snap = eng.obs_snapshot()["router"]
-    assert snap == {"route_slots": 4 * 2, "route_useful": 3}
+    assert snap == {"route_slots": 4 * 2, "route_useful": 3,
+                    "route_resorted": 0, "route_resorted_rows": 0}
     text = eng._obs.prometheus()
     assert "# TYPE repro_obs_engines_engine0_router_route_slots counter" \
         in text.splitlines()
+
+
+def test_router_counts_streams_it_resorted():
+    eng, tr = _engine()
+    # in position order: no sort, whatever the order of the streams
+    eng.ingest([2, 0, 1, 0, 1], np.arange(5.0), [0, 0, 0, 1, 1])
+    # streams 1 and 3 out of position order, stream 0 in it
+    eng.ingest([1, 0, 3, 1, 3, 0, 3], np.arange(7.0),
+               [3, 2, 9, 2, 8, 3, 7])
+    eng.ingest([3, 3], [1.0, 2.0], [10, 11])
+    snap = eng.obs_snapshot()["router"]
+    assert (snap["route_resorted"], snap["route_resorted_rows"]) == (1, 2)
+    route = [e for e in _chunk_spans(tr) if e["name"] == "ingest.route"]
+    assert [e["attrs"]["resorted"] for e in route] == [0, 2, 0]
+    text = eng._obs.prometheus().splitlines()
+    assert "# TYPE repro_obs_engines_engine0_router_route_resorted " \
+        "counter" in text
+    assert "repro_obs_engines_engine0_router_route_resorted_rows 2" in text
 
 
 def test_trace_ingest_off_records_none_and_outputs_bit_equal():

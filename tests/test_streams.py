@@ -503,6 +503,145 @@ def test_router_rejects_within_batch_duplicate_doc():
     rt.route([1, 2], [0.0, 0.0], [5, 5])
 
 
+def _route_reference(buckets, stream_ids, scores, doc_ids, pad_to=None):
+    """Plain ``StreamRouter.route``: a dict lookup, then one lexsort by
+    (row, doc id) per bucket and a 2-D scatter."""
+    where = {sid: (bi, row) for bi, b in enumerate(buckets)
+             for row, sid in enumerate(b.stream_ids)}
+    bi = np.asarray([where[int(s)][0] for s in stream_ids], np.int64)
+    row = np.asarray([where[int(s)][1] for s in stream_ids], np.int64)
+    scores = np.asarray(scores, np.float32)
+    doc_ids = np.asarray(doc_ids, np.int32)
+    out = []
+    for b_idx, bucket in enumerate(buckets):
+        sel = np.flatnonzero(bi == b_idx)
+        order = np.lexsort((doc_ids[sel], row[sel]))
+        rows = row[sel][order]
+        counts = np.bincount(rows, minlength=bucket.m)
+        pos = np.arange(rows.size) - np.repeat(np.cumsum(counts) - counts,
+                                               counts)
+        w = 1
+        while w < counts.max(initial=0):
+            w *= 2
+        w = max(w, pad_to or 0)
+        s = np.full((bucket.m, w), router.PAD_SCORE, np.float32)
+        i = np.full((bucket.m, w), router.PAD_ID, np.int32)
+        s[rows, pos] = scores[sel][order]
+        i[rows, pos] = doc_ids[sel][order]
+        out.append((s, i))
+    return out
+
+
+def _fleet(m, sparse):
+    """m streams over three K and both engines (six buckets where m allows);
+    sparse ids are spread out and start negative, so the router
+    binary-searches them instead of keeping a dense table."""
+    sids = 1000 * np.arange(m) - 5 if sparse else np.arange(m) + 3
+    ks = {int(s): (2, 4, 8)[j % 3] for j, s in enumerate(sids)}
+    engines = {int(s): ("exact", "logmem")[j % 2] for j, s in enumerate(sids)}
+    return sids, router.bucket_streams(ks, engines)
+
+
+def _batch(sids, docs, order, seed=0):
+    """``docs`` (stream, score, doc id) triples, each stream's doc ids
+    ascending in batch order (its positions), then re-ordered."""
+    rng = np.random.default_rng(seed)
+    j = rng.integers(0, sids.size, docs)
+    ranks = np.empty(docs, np.int64)
+    grouped = np.argsort(j, kind="stable")
+    counts = np.bincount(j, minlength=sids.size)
+    ranks[grouped] = np.arange(docs) - np.repeat(np.cumsum(counts) - counts,
+                                                 counts)
+    stream = sids[j]
+    scores = rng.standard_normal(docs).astype(np.float32)
+    ids = (1_000_000 + 3 * ranks).astype(np.int32)
+    if order == "shuffled":
+        p = rng.permutation(docs)
+        stream, scores, ids = stream[p], scores[p], ids[p]
+    elif order == "one_row":
+        # swap two documents of one stream: only its row is out of order
+        a, b = np.flatnonzero(j == np.argmax(counts >= 2))[:2]
+        scores[[a, b]], ids[[a, b]] = scores[[b, a]], ids[[b, a]]
+    return stream, scores, ids
+
+
+ROUTE_CASES = {
+    # name: (streams, sparse ids, docs, order, pad_to)
+    "in_order": (300, False, 1200, "in_order", None),
+    "shuffled": (300, False, 1200, "shuffled", None),
+    "sparse_in_order": (300, True, 1200, "in_order", None),
+    "sparse_shuffled": (300, True, 1200, "shuffled", None),
+    "pad_to": (300, False, 1200, "shuffled", 64),
+    "empty_batch": (300, False, 0, "in_order", None),
+    "single_stream": (1, False, 37, "shuffled", None),
+    "one_row_out_of_order": (300, True, 1200, "one_row", None),
+    "few_streams": (5, False, 40, "shuffled", None),
+    "wide_fleet_in_order": ((1 << 16) + 40, False, 150_000, "in_order",
+                            None),
+    "wide_fleet_shuffled": ((1 << 16) + 40, False, 150_000, "shuffled",
+                            None),
+}
+
+
+@pytest.mark.parametrize("case", list(ROUTE_CASES))
+def test_router_matches_lexsort_reference(case):
+    m, sparse, docs, order, pad_to = ROUTE_CASES[case]
+    sids, buckets = _fleet(m, sparse)
+    rt = router.StreamRouter(buckets)
+    assert (rt._table is None) == sparse  # both lookup branches run
+    stream, scores, ids = _batch(sids, docs, order)
+    got = rt.route(stream, scores, ids, pad_to=pad_to)
+    want = _route_reference(buckets, stream, scores, ids, pad_to)
+    assert len(got) == len(want) == len(buckets)
+    for (gs, gi), (ws, wi) in zip(got, want):
+        assert (gs.dtype, gi.dtype) == (np.float32, np.int32)
+        np.testing.assert_array_equal(gs, ws)
+        np.testing.assert_array_equal(gi, wi)
+    shuffled_rows = {"in_order": 0, "one_row": 1}.get(order)
+    if shuffled_rows is not None:
+        assert rt.route_resorted_rows == shuffled_rows
+        assert rt.route_resorted == int(shuffled_rows > 0)
+    elif docs > m:
+        assert rt.route_resorted == 1 and rt.route_resorted_rows > 0
+
+
+@pytest.mark.parametrize("sparse", [False, True])
+@pytest.mark.parametrize("order", ["in_order", "shuffled"])
+def test_router_rejects_duplicate_on_both_paths(sparse, order):
+    sids, buckets = _fleet(8, sparse)
+    rt = router.StreamRouter(buckets)
+    stream, scores, ids = _batch(sids, 64, "in_order", seed=3)
+    first = np.flatnonzero(stream == sids[5])[:2]
+    ids[first[1]] = doc = ids[first[0]]  # stream sids[5] sees doc twice
+    if order == "shuffled":
+        p = np.random.default_rng(4).permutation(stream.size)
+        stream, scores, ids = stream[p], scores[p], ids[p]
+    counted = (rt.route_slots, rt.route_useful, rt.route_resorted,
+               rt.route_resorted_rows)
+    msg = (f"duplicate (stream, doc) in one batch: stream {sids[5]} doc "
+           f"{doc} — a doc id may appear once per stream per ingest")
+    with pytest.raises(ValueError) as err:
+        rt.route(stream, scores, ids)
+    assert str(err.value) == msg
+    assert (rt.route_slots, rt.route_useful, rt.route_resorted,
+            rt.route_resorted_rows) == counted  # nothing counted
+
+
+@pytest.mark.parametrize("sparse", [False, True])
+@pytest.mark.parametrize("bad", ["negative", "out_of_range", "unregistered"])
+def test_router_rejects_unregistered_ids(sparse, bad):
+    sids = (1000 * np.arange(6) + 7) if sparse else 2 * np.arange(6)
+    rt = router.StreamRouter(router.bucket_streams(
+        {int(s): 4 for s in sids}))
+    assert (rt._table is None) == sparse
+    bad_id = {"negative": -2, "out_of_range": int(sids.max()) * 10 + 1,
+              "unregistered": int(sids[2]) + 1}[bad]
+    with pytest.raises(KeyError, match=rf"unregistered stream ids: "
+                                       rf"\[{bad_id}\]"):
+        rt.route([int(sids[0]), bad_id, int(sids[1])], [0.0] * 3, [0] * 3)
+    assert (rt.route_slots, rt.route_useful) == (0, 0)
+
+
 def test_reconcile_ignores_idle_streams():
     eng = StreamEngine([StreamSpec(stream_id=0, k=2, r=8.0),
                         StreamSpec(stream_id=1, k=2, r=8.0)])
